@@ -64,7 +64,10 @@ print(json.dumps(results))
 
 
 def run():
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # the child analyses virtual CPU devices; pinning its platform keeps it
+    # off any accelerator this process (or another) holds
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(SNIPPET)],
                          capture_output=True, text=True, env=env,

@@ -36,7 +36,7 @@ import json
 import jax
 import jax.numpy as jnp
 from repro.configs.base import get_config
-from repro.core.parallelism_selector import (HBM_BYTES, ProfileEntry,
+from repro.core.parallelism_selector import (ProfileEntry,
                                              make_cost_model_measure)
 from repro.utils.roofline import H100, V5E
 from repro.core.resharding import MeshConfig
@@ -67,7 +67,7 @@ def lower_decode(mesh_cfg, ctx, responses):
     serve = make_serve_step(model)
     jf = jax.jit(serve, in_shardings=(p_sh, t_sh, c_sh),
                  donate_argnums=(2,))
-    with mesh:
+    with jax.set_mesh(mesh):
         return jf.lower(params, token, cache)
 
 
@@ -102,7 +102,10 @@ print(json.dumps(rows))
 
 
 def run():
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # the child analyses virtual CPU devices; pinning its platform keeps it
+    # off any accelerator this process (or another) holds
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(SNIPPET)],
                          capture_output=True, text=True, env=env,

@@ -5,11 +5,15 @@ Shows the paper's Fig. 4 effect structurally: the single-controller path
 funnels the whole batch through one device; the layout-aware all-to-all
 moves only the shards that change owner.
 
-    python examples/dispatch_demo.py            # sets its own XLA_FLAGS
+    python examples/dispatch_demo.py
+
+It sets its own XLA_FLAGS and pins JAX_PLATFORMS=cpu: the 16 devices are
+virtual CPU devices, on any machine, chip or not.
 """
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=16")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 import jax.numpy as jnp

@@ -40,9 +40,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.resharding import MeshConfig
 
-# TPU v5e HBM per chip (16 GiB); the OOM feasibility threshold.
-HBM_BYTES = 16 * 2**30
-
 
 @dataclass(frozen=True)
 class ContextBuckets:
@@ -207,13 +204,13 @@ class ParallelismSelector:
 # ---------------------------------------------------------------------------
 
 def make_cost_model_measure(lower_fn: Callable[[MeshConfig, int], object],
-                            *, hbm_bytes: float = HBM_BYTES,
-                            seq_tokens_fn: Callable[[int], float] = None,
-                            hw=None) -> MeasureFn:
+                            *, hw, seq_tokens_fn: Callable[[int], float] =
+                            None) -> MeasureFn:
     """Build a MeasureFn from a ``lower_fn(config, context_len) ->
     jax.stages.Lowered``. Compiles the stage program and scores TGS with
-    the v5e roofline; marks the config infeasible when the compiled
-    per-device footprint exceeds HBM (the paper's OOM case).
+    the roofline of ``hw`` (a ``utils.roofline.HardwareSpec``); marks the
+    config infeasible when the compiled per-device footprint exceeds
+    ``hw.hbm_bytes`` (the paper's OOM case).
 
     seq_tokens_fn(context_len) -> tokens processed per step (global); the
     TGS denominator. Defaults to context_len (decode: one step covers the
@@ -243,9 +240,8 @@ def make_cost_model_measure(lower_fn: Callable[[MeshConfig, int], object],
         tokens = (seq_tokens_fn(context_len) if seq_tokens_fn
                   else float(context_len))
         tgs = tokens / max(config.n_devices, 1) / max(t, 1e-12)
-        budget = hw.hbm_bytes if hw is not None else hbm_bytes
         return ProfileEntry(config, context_len, tgs,
-                            feasible=peak <= budget, peak_bytes=peak,
+                            feasible=peak <= hw.hbm_bytes, peak_bytes=peak,
                             step_time_s=t)
 
     return measure

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import matmul_nt, matmul_tn, resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -42,18 +44,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref, dq_ref, *,
     qi = pl.program_id(2) * bq
     q = q_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    Lrow = L_ref[0, 0]                                     # (bq,)
-    Drow = D_ref[0, 0]                                     # (bq,)
+    Lrow = L_ref[0, 0]                                     # (bq, 1)
+    Drow = D_ref[0, 0]                                     # (bq, 1)
 
     def body(j, dq):
-        k_blk = k_ref[0, 0, pl.dslice(j * bk, bk), :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.dslice(j * bk, bk), :].astype(jnp.float32)
-        s = (q @ k_blk.T) * scale                          # (bq, bk)
+        k_blk = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
+        v_blk = v_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
+        s = matmul_nt(q, k_blk) * scale                    # (bq, bk)
         ok = _mask(bq, bk, qi, j * bk, causal=causal, window=window)
-        p = jnp.where(ok, jnp.exp(s - Lrow[:, None]), 0.0)
-        dp = do @ v_blk.T                                  # (bq, bk)
-        ds = p * (dp - Drow[:, None])
-        return dq + ds @ k_blk
+        p = jnp.where(ok, jnp.exp(s - Lrow), 0.0)
+        dp = matmul_nt(do, v_blk)                          # (bq, bk)
+        ds = p * (dp - Drow)
+        return dq + jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
 
     dq = jax.lax.fori_loop(0, seq_k // bk, body,
                            jnp.zeros((bq, hd), jnp.float32))
@@ -73,17 +75,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref,
         dk, dv = carry
         g = gi // (seq_q // bq)
         i = gi % (seq_q // bq)
-        q = q_ref[0, 0, g, pl.dslice(i * bq, bq), :].astype(jnp.float32)
-        do = do_ref[0, 0, g, pl.dslice(i * bq, bq), :].astype(jnp.float32)
-        Lrow = L_ref[0, 0, g, pl.dslice(i * bq, bq)]
-        Drow = D_ref[0, 0, g, pl.dslice(i * bq, bq)]
-        s = (q @ k_blk.T) * scale                          # (bq, bk)
+        q = q_ref[0, 0, g, pl.ds(i * bq, bq), :].astype(jnp.float32)
+        do = do_ref[0, 0, g, pl.ds(i * bq, bq), :].astype(jnp.float32)
+        Lrow = L_ref[0, 0, g, pl.ds(i * bq, bq), :]        # (bq, 1)
+        Drow = D_ref[0, 0, g, pl.ds(i * bq, bq), :]
+        s = matmul_nt(q, k_blk) * scale                    # (bq, bk)
         ok = _mask(bq, bk, i * bq, kj, causal=causal, window=window)
-        p = jnp.where(ok, jnp.exp(s - Lrow[:, None]), 0.0)
-        dv = dv + p.T @ do
-        dp = do @ v_blk.T
-        ds = p * (dp - Drow[:, None])
-        dk = dk + ds.T @ q
+        p = jnp.where(ok, jnp.exp(s - Lrow), 0.0)
+        dv = dv + matmul_tn(p, do)
+        dp = matmul_nt(do, v_blk)
+        ds = p * (dp - Drow)
+        dk = dk + matmul_tn(ds, q)
         return dk, dv
 
     n = group * (seq_q // bq)
@@ -95,8 +97,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref,
 
 
 def flash_attention_bwd_bhsd(q, k, v, o, do, L, *, causal=True, window=0,
-                             bq=128, bk=128, interpret=False):
-    """Backward pass. q,o,do: (B,H,S,hd); k,v: (B,KV,Sk,hd); L: (B,H,S).
+                             bq=128, bk=128, interpret=None):
+    """Backward pass. q,o,do: (B,H,S,hd); k,v: (B,KV,Sk,hd); L: (B,H,S,1).
     Returns (dq (B,H,S,hd), dk (B,KV,Sk,hd), dv (B,KV,Sk,hd))."""
     B, H, S, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
@@ -105,7 +107,9 @@ def flash_attention_bwd_bhsd(q, k, v, o, do, L, *, causal=True, window=0,
     bk = min(bk, Sk)
     assert S % bq == 0 and Sk % bk == 0
     scale = 1.0 / (hd ** 0.5)
-    D = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    interpret = resolve_interpret(interpret)
+    D = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                keepdims=True)                             # (B,H,S,1)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -116,8 +120,8 @@ def flash_attention_bwd_bhsd(q, k, v, o, do, L, *, causal=True, window=0,
             pl.BlockSpec((1, 1, Sk, hd), lambda b, h, i: (b, h // group, 0, 0)),
             pl.BlockSpec((1, 1, Sk, hd), lambda b, h, i: (b, h // group, 0, 0)),
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
@@ -127,8 +131,8 @@ def flash_attention_bwd_bhsd(q, k, v, o, do, L, *, causal=True, window=0,
     # group the H q-heads by their kv head for the dk/dv pass
     qg = q.reshape(B, KV, group, S, hd)
     dog = do.reshape(B, KV, group, S, hd)
-    Lg = L.reshape(B, KV, group, S)
-    Dg = D.reshape(B, KV, group, S)
+    Lg = L.reshape(B, KV, group, S, 1)
+    Dg = D.reshape(B, KV, group, S, 1)
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
@@ -141,8 +145,10 @@ def flash_attention_bwd_bhsd(q, k, v, o, do, L, *, causal=True, window=0,
             pl.BlockSpec((1, 1, bk, hd), lambda b, g, j: (b, g, j, 0)),
             pl.BlockSpec((1, 1, group, S, hd),
                          lambda b, g, j: (b, g, 0, 0, 0)),
-            pl.BlockSpec((1, 1, group, S), lambda b, g, j: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, group, S), lambda b, g, j: (b, g, 0, 0)),
+            pl.BlockSpec((1, 1, group, S, 1),
+                         lambda b, g, j: (b, g, 0, 0, 0)),
+            pl.BlockSpec((1, 1, group, S, 1),
+                         lambda b, g, j: (b, g, 0, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, hd), lambda b, g, j: (b, g, j, 0)),

@@ -2,7 +2,8 @@
 
 ``flash_attention`` takes model-layout tensors (B, S, H, hd) and handles:
   - layout transpose to the kernels' (B, H, S, hd);
-  - block-size selection (MXU-aligned 128 where the sequence allows);
+  - block-size selection (MXU-aligned 128 where the sequence allows,
+    else a multiple of 8, else the whole sequence);
   - a custom VJP whose backward is the Pallas two-pass flash backward
     (bwd_kernel.py) — P is recomputed blockwise from the saved softmax
     normalizers L, so neither direction materializes O(S²) tensors.
@@ -14,20 +15,18 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pick_block
 from repro.kernels.flash_attention import ref
 from repro.kernels.flash_attention.bwd_kernel import flash_attention_bwd_bhsd
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 
 
-def _pick_block(s: int, target: int = 128) -> int:
-    b = min(target, s)
-    while s % b:
-        b //= 2
-    return max(b, 1)
+def _pick_block(s: int) -> int:
+    return pick_block(s, 128, 8)            # rows ride the sublane dim
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, causal=True, window=0, interpret=False):
+def flash_attention(q, k, v, causal=True, window=0, interpret=None):
     """q: (B,S,H,hd); k,v: (B,Sk,KV,hd) -> (B,S,H,hd)."""
     out, _ = _forward(q, k, v, causal, window, interpret)
     return out

@@ -18,6 +18,12 @@ interpret mode, so the Gumbel noise is a regular operand generated with
 ``jax.random`` by the wrapper — which also makes temperature sampling
 bitwise ``jax.random.categorical`` (same key, same noise). A TPU-only
 follow-on can seed the PRNG in-kernel and drop the operand.
+
+TPU tiling: the grid walks (row blocks, vocab blocks). A block is ``rb``
+rows (the whole batch up to 8, else 8) by ``block_v`` lanes, a multiple
+of 128; ``BLOCK_V`` keeps two double-buffered f32 operand blocks at
+8 x 8192 (1 MiB in all) far inside the chip's scoped VMEM at any vocab.
+Per-row state is (rb, 1) columns.
 """
 from __future__ import annotations
 
@@ -28,7 +34,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
+BLOCK_V = 8192        # vocab lanes per block (a multiple of 128)
+ROW_BLOCK = 8         # f32 sublanes per vreg
 
 
 def _fused_sample_kernel(lg_ref, noise_ref, tok_ref, lp_ref,
@@ -44,80 +54,86 @@ def _fused_sample_kernel(lg_ref, noise_ref, tok_ref, lp_ref,
         bi_ref[...] = jnp.zeros_like(bi_ref)
         bl_ref[...] = jnp.full_like(bl_ref, NEG_INF)
 
-    lg = lg_ref[0].astype(jnp.float32)                      # (bv,)
-    noise = noise_ref[0].astype(jnp.float32)
+    lg = lg_ref[...].astype(jnp.float32)                    # (rb, bv)
+    noise = noise_ref[...].astype(jnp.float32)
 
     # online logsumexp of the clean logits (the log-prob denominator)
-    m_prev = m_ref[0]
-    m_new = jnp.maximum(m_prev, jnp.max(lg))
-    l_ref[0] = l_ref[0] * jnp.exp(m_prev - m_new) \
-        + jnp.sum(jnp.exp(lg - m_new))
-    m_ref[0] = m_new
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(lg, axis=1, keepdims=True))
+    l_ref[...] = l_ref[...] * jnp.exp(m_prev - m_new) \
+        + jnp.sum(jnp.exp(lg - m_new), axis=1, keepdims=True)
+    m_ref[...] = m_new
 
     # running argmax of the perturbed logits, carrying the winner's CLEAN
-    # logit for the numerator. Strict > keeps the earliest max on ties —
-    # the same tie-break as a global argmax.
+    # logit for the numerator. The block's first maximal lane, and strict
+    # > across blocks, keep the earliest max on ties — the same tie-break
+    # as a global argmax.
     score = lg + noise
-    barg = jnp.argmax(score)
-    bmax = jnp.max(score)
-    blog = jnp.sum(jnp.where(
-        jax.lax.broadcasted_iota(jnp.int32, (bv,), 0) == barg, lg, 0.0))
-    better = bmax > bs_ref[0]
-    bs_ref[0] = jnp.where(better, bmax, bs_ref[0])
-    bi_ref[0] = jnp.where(better, v * bv + barg.astype(jnp.int32),
-                          bi_ref[0])
-    bl_ref[0] = jnp.where(better, blog, bl_ref[0])
+    lane = jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
+    bmax = jnp.max(score, axis=1, keepdims=True)            # (rb, 1)
+    barg = jnp.min(jnp.where(score == bmax, lane, bv), axis=1,
+                   keepdims=True)
+    blog = jnp.sum(jnp.where(lane == barg, lg, 0.0), axis=1, keepdims=True)
+    better = bmax > bs_ref[...]
+    bs_ref[...] = jnp.where(better, bmax, bs_ref[...])
+    bi_ref[...] = jnp.where(better, v * bv + barg, bi_ref[...])
+    bl_ref[...] = jnp.where(better, blog, bl_ref[...])
 
     @pl.when(v == n_blocks - 1)
     def _finish():
-        tok_ref[0, 0] = bi_ref[0]
-        lp_ref[0, 0] = bl_ref[0] - (m_ref[0] + jnp.log(l_ref[0]))
+        tok_ref[...] = bi_ref[...]
+        lp_ref[...] = bl_ref[...] - (m_ref[...] + jnp.log(l_ref[...]))
 
 
-def fused_sample_bkgd(lg, noise, *, block_v: int = 1024, interpret=False):
+def fused_sample_bkgd(lg, noise, *, block_v: int = BLOCK_V, interpret=None):
     """lg: (B, V) f32 logits (already tempered / top-p masked); noise:
     (B, V) f32 additive perturbation (Gumbel; zeros = greedy). Returns
     ``(tokens (B,) int32, logprobs (B,) f32)`` with ``tokens = argmax(lg
     + noise)`` and ``logprobs = lg[tok] - logsumexp(lg)``."""
     B, V = lg.shape
-    bv = min(block_v, V)
+    assert block_v % 128 == 0, block_v
+    bv = V if V <= block_v else block_v
     n_blocks = -(-V // bv)
-    pad = n_blocks * bv - V
-    if pad:
-        # NEG_INF logit pad: zero mass in the logsumexp, never the argmax
-        lg = jnp.pad(lg, ((0, 0), (0, pad)), constant_values=NEG_INF)
-        noise = jnp.pad(noise, ((0, 0), (0, pad)))
+    rb = B if B <= ROW_BLOCK else ROW_BLOCK
+    pad_v = n_blocks * bv - V
+    pad_b = -B % rb
+    if pad_v or pad_b:
+        # NEG_INF logit pad: zero mass in the logsumexp, never the argmax;
+        # padded rows are dropped below
+        lg = jnp.pad(lg, ((0, pad_b), (0, pad_v)), constant_values=NEG_INF)
+        noise = jnp.pad(noise, ((0, pad_b), (0, pad_v)))
     kernel = functools.partial(_fused_sample_kernel, bv=bv,
                                n_blocks=n_blocks)
 
-    def blk_map(b, v):
-        return (b, v)
+    def blk_map(r, v):
+        return (r, v)
 
-    def row_map(b, v):
-        return (b, 0)
+    def row_map(r, v):
+        return (r, 0)
 
+    col = lambda dtype: pltpu.VMEM((rb, 1), dtype)
     tok, lp = pl.pallas_call(
         kernel,
-        grid=(B, n_blocks),
+        grid=((B + pad_b) // rb, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, bv), blk_map),
-            pl.BlockSpec((1, bv), blk_map),
+            pl.BlockSpec((rb, bv), blk_map),
+            pl.BlockSpec((rb, bv), blk_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1), row_map),
-            pl.BlockSpec((1, 1), row_map),
+            pl.BlockSpec((rb, 1), row_map),
+            pl.BlockSpec((rb, 1), row_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B + pad_b, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B + pad_b, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),      # running max m
-            pltpu.VMEM((1,), jnp.float32),      # running sum l
-            pltpu.VMEM((1,), jnp.float32),      # best perturbed score
-            pltpu.VMEM((1,), jnp.int32),        # best token index
-            pltpu.VMEM((1,), jnp.float32),      # best clean logit
+            col(jnp.float32),      # running max m
+            col(jnp.float32),      # running sum l
+            col(jnp.float32),      # best perturbed score
+            col(jnp.int32),        # best token index
+            col(jnp.float32),      # best clean logit
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lg.astype(jnp.float32), noise.astype(jnp.float32))
-    return tok[:, 0], lp[:, 0]
+    return tok[:B, 0], lp[:B, 0]

@@ -32,7 +32,7 @@ def apply_top_p(lg, top_p: float):
 
 
 def fused_sample_tokens(rng, logits, temperature: float, *,
-                        top_p: float = 1.0, interpret=False):
+                        top_p: float = 1.0, interpret=None):
     """Sample next tokens from (B, V) logits in one kernel pass. Returns
     ``(tokens, logprobs)`` — ``common.sample_tokens`` semantics:
     ``temperature <= 0`` is greedy argmax with log-probs from the

@@ -8,7 +8,7 @@ from repro.kernels.paged_attention.kernel import paged_decode_attention_bkgd
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, lens, *,
-                           k_scales=None, v_scales=None, interpret=False):
+                           k_scales=None, v_scales=None, interpret=None):
     """q: (B,H,hd) one query per row; k_pages,v_pages: (P,ps,KV,hd) shared
     page pool; block_table: (B,NP) int32 (-1 = unmapped); lens: (B,) int32
     live tokens per row. k_scales/v_scales: optional (P,ps,KV) f32 scale

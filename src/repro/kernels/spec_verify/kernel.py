@@ -24,77 +24,20 @@ Why this is nearly free relative to K single-token decode steps: decode
 attention is memory-bound on the pool read, and the pool pages are read
 ONCE here for all K queries (q block ``(K*group, hd)`` vs
 ``(group, hd)``) — the arithmetic grows K-fold but the HBM traffic does
-not. Grid and online-softmax scratch mirror ``kernels/paged_attention``;
-int8 pools dequantize in-register via the same scale-pool prefetch
-specs.
+not. The kernel is ``kernels/paged_attention``'s row kernel with a
+per-query offset: chunk position ``j`` attends ``<= pos[b]+j``; int8 pools
+dequantize in-register via the same scale-pool prefetch specs.
 """
 from __future__ import annotations
 
-import functools
-import math
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
-
-
-def _spec_verify_kernel(bt_safe_ref, bt_ref, pos_ref, q_ref, k_ref, v_ref,
-                        *refs, scale, ps, n_pages_grid, quantized, group):
-    del bt_safe_ref                    # consumed by the BlockSpec index maps
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    else:
-        o_ref, m_ref, l_ref, acc_ref = refs
-    kq = q_ref.shape[2]                # K * group query rows
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale            # (KQ, hd)
-    k_blk = k_ref[0, :, 0].astype(jnp.float32)             # (ps, hd)
-    v_blk = v_ref[0, :, 0].astype(jnp.float32)
-    if quantized:
-        k_blk = k_blk * ks_ref[0, :, 0][:, None]
-        v_blk = v_blk * vs_ref[0, :, 0][:, None]
-
-    # per-query validity: query row r covers chunk position j = r//group at
-    # absolute position pos[b]+j, and attends pool positions <= pos[b]+j —
-    # the causal-within-chunk mask falls out of the per-query length
-    idx = p * ps + jax.lax.broadcasted_iota(jnp.int32, (kq, ps), 1)
-    jrow = jax.lax.broadcasted_iota(jnp.int32, (kq, ps), 0) // group
-    ok = (idx <= pos_ref[b] + jrow) & (bt_ref[b, p] >= 0)  # (KQ, ps)
-
-    s = q @ k_blk.T                                        # (KQ, ps)
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    pr = jnp.exp(s - m_new[:, None])
-    pr = jnp.where(ok, pr, 0.0)        # masked cols contribute exactly 0
-    alpha = jnp.exp(m_prev - m_new)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pr @ v_blk
-    m_ref[...] = m_new
-    l_ref[...] = alpha * l_prev + jnp.sum(pr, axis=1)
-
-    @pl.when(p == n_pages_grid - 1)
-    def _finish():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)                    # fully masked row
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+from repro.kernels.paged_attention.kernel import paged_attention_rows
 
 
 def spec_verify_attention_bkgd(q, k_pages, v_pages, block_table, pos, *,
                                group, k_scales=None, v_scales=None,
-                               interpret=False):
+                               interpret=None):
     """q: (B,KV,K*group,hd) — K query positions flattened position-major
     into the row axis (row ``j*group + g`` is chunk position ``j``, GQA
     member ``g``); k_pages,v_pages: (P,ps,KV,hd) shared page pool (chunk
@@ -102,55 +45,8 @@ def spec_verify_attention_bkgd(q, k_pages, v_pages, block_table, pos, *,
     pos: (B,) int32 base positions — query j attends pool positions
     ``<= pos[b]+j``. k_scales/v_scales: optional (P,ps,KV) f32 int8-pool
     scales. -> (B,KV,K*group,hd)."""
-    B, KV, kq, hd = q.shape
-    P, ps = k_pages.shape[0], k_pages.shape[1]
-    NP = block_table.shape[1]
-    scale = 1.0 / math.sqrt(hd)
-    quantized = k_scales is not None
-    kernel = functools.partial(_spec_verify_kernel, scale=scale, ps=ps,
-                               n_pages_grid=NP, quantized=quantized,
-                               group=group)
-    bt_safe = jnp.clip(block_table, 0, P - 1).astype(jnp.int32)
-
-    def page_map(b, h, p, bt_safe, bt, pos):
-        del bt, pos
-        return (bt_safe[b, p], 0, h, 0)
-
-    def scale_map(b, h, p, bt_safe, bt, pos):
-        del bt, pos
-        return (bt_safe[b, p], 0, h)
-
-    def row_map(b, h, p, bt_safe, bt, pos):
-        del bt_safe, bt, pos
-        return (b, h, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, kq, hd), row_map),
-        pl.BlockSpec((1, ps, 1, hd), page_map),
-        pl.BlockSpec((1, ps, 1, hd), page_map),
-    ]
-    operands = [q, k_pages, v_pages]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_map),
-                     pl.BlockSpec((1, ps, 1), scale_map)]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, KV, NP),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, kq, hd), row_map),
-        scratch_shapes=[
-            pltpu.VMEM((kq,), jnp.float32),      # running max m
-            pltpu.VMEM((kq,), jnp.float32),      # running sum l
-            pltpu.VMEM((kq, hd), jnp.float32),   # output accumulator
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, kq, hd), q.dtype),
-        interpret=interpret,
-    )(bt_safe, block_table.astype(jnp.int32), pos.astype(jnp.int32),
-      *operands)
+    kq = q.shape[2]
+    offsets = jnp.arange(kq, dtype=jnp.int32) // group
+    return paged_attention_rows(q, k_pages, v_pages, block_table, pos,
+                                offsets, k_scales=k_scales,
+                                v_scales=v_scales, interpret=interpret)

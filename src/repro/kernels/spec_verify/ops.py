@@ -8,7 +8,7 @@ from repro.kernels.spec_verify.kernel import spec_verify_attention_bkgd
 
 
 def spec_verify_attention(q, k_pages, v_pages, block_table, pos, *,
-                          k_scales=None, v_scales=None, interpret=False):
+                          k_scales=None, v_scales=None, interpret=None):
     """q: (B,K,H,hd) K candidate queries per row, chunk K/V already
     bulk-scattered into the pool at absolute positions
     ``pos[b]..pos[b]+K-1``; k_pages,v_pages: (P,ps,KV,hd) shared page

@@ -14,7 +14,7 @@ from repro.kernels.ssd_scan.kernel import ssd_scan_bhcp
 
 
 def ssd_scan(x, dt, A, B, C, chunk_size: int, initial_state=None,
-             *, interpret=False):
+             *, interpret=None):
     """x: (b,s,h,p); dt: (b,s,h) (softplus'ed); A: (h,) negative;
     B,C: (b,s,g,n). Returns (y (b,s,h,p), final_state (b,h,p,n) f32)."""
     assert initial_state is None, "kernel path starts from zero state"

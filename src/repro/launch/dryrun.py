@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# The two lines above MUST run before jax initializes: the dry-run builds
-# the production 16x16 (and 2x16x16) mesh from host placeholder devices.
+# The lines above MUST run before jax initializes: the dry-run builds the
+# production 16x16 (and 2x16x16) mesh from host placeholder CPU devices,
+# and never asks for an accelerator another process may hold.
 # Everything below proves the distribution config is coherent without TPU
 # hardware: every (architecture x input-shape x mesh) stage program must
 # lower + compile, and the compiled artifact yields the roofline terms
@@ -130,7 +132,7 @@ def run_one(arch_id: str, shape_name: str, *, multi_pod: bool,
     jit_fn, args, meta = build_stage(arch_id, shape_name, mesh, fsdp=fsdp,
                                      rules=rules, remat=remat,
                                      microbatch=microbatch)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jit_fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -146,7 +148,7 @@ def run_one(arch_id: str, shape_name: str, *, multi_pod: bool,
         name, chips=chips,
         cost_analysis={"flops": fc.flops, "bytes accessed": fc.bytes_accessed},
         collective_bytes=fc.collective_bytes, model_flops=mf,
-        peak_memory_bytes=peak_bytes(mem))
+        hw=roofline.V5E, peak_memory_bytes=peak_bytes(mem))
 
     row = {
         "arch": arch_id, "shape": shape_name,

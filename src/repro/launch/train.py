@@ -3,6 +3,10 @@
     PYTHONPATH=src python -m repro.launch.train \
         --arch qwen2-0.5b --env tictactoe --steps 50 --batch 16
 
+The model runs at the architecture's published widths and depth
+(``configs/<arch>.py``, CONFIG); ``--smoke`` selects the reduced
+SMOKE_CONFIG of the same family, sized for CPU tests.
+
 Runs the full EARL system on the available devices: multi-turn rollouts,
 experience preparation with a frozen reference model (folded into the
 rollout macro-step), layout-aware dispatch, policy-gradient update, with
@@ -22,11 +26,12 @@ from pathlib import Path
 
 import jax
 
-from repro.configs.base import get_smoke_config
+from repro.configs.base import get_config, get_smoke_config
 from repro.core.stages import EarlTrainer
 from repro.models.registry import build_model
 from repro.optim.adamw import adamw
 from repro.rl.envs import make_env
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.faults import FaultInjector
 
 
@@ -157,8 +162,10 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log", default="train_log.jsonl")
     ap.add_argument("--smoke", action="store_true",
-                    help="use the reduced smoke config (CPU-sized)")
+                    help="use the reduced smoke config of --arch (CPU-"
+                         "sized) instead of its published widths")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.rollout_episodes is not None and args.rollout_backend != \
             "compiled":
@@ -166,9 +173,7 @@ def main(argv=None):
               "backend (slot refill); ignoring it", file=sys.stderr)
         args.rollout_episodes = None
 
-    # CPU containers always use the smoke config; the full config is for
-    # real accelerators (it would not fit host memory here).
-    cfg = get_smoke_config(args.arch)
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     env = make_env(args.env)
 

@@ -31,17 +31,10 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 
 def _ambient_mesh():
-    """The mesh installed by ``with mesh:`` around jit/lower, or None.
-    Model code runs unchanged on a single device (no mesh -> no-op)."""
-    try:
-        import warnings
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters import pxla
-            m = pxla.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    """The mesh installed by ``jax.set_mesh(mesh)`` around jit/lower, or
+    None. Model code runs unchanged on a single device (no mesh -> no-op)."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def constrain(x, *axes):
@@ -231,8 +224,7 @@ def self_attention(p, x, *, n_heads, n_kv_heads, head_dim, rope_theta,
         mask = causal_mask(S, S, window=window)
     if attn_impl == "pallas" and cross_kv is None:
         from repro.kernels.flash_attention import ops as fa_ops
-        out = fa_ops.flash_attention(q, k, v, causal=True, window=window,
-                                     interpret=True)
+        out = fa_ops.flash_attention(q, k, v, causal=True, window=window)
     else:
         out = _sdpa(q, k, v, mask)
     out = out.reshape(B, S, n_heads * head_dim)
@@ -310,8 +302,7 @@ def prefill_attention(p, x, kv: KVEntry, *, n_heads, n_kv_heads, head_dim,
     mask = causal_mask(S, S, window=window)
     if attn_impl == "pallas":
         from repro.kernels.flash_attention import ops as fa_ops
-        out = fa_ops.flash_attention(q, k, v, causal=True, window=window,
-                                     interpret=True)
+        out = fa_ops.flash_attention(q, k, v, causal=True, window=window)
     else:
         out = _sdpa(q, k, v, mask)
     out = out.reshape(B, S, n_heads * head_dim)
@@ -370,7 +361,7 @@ def decode_attention(p, x, kv: KVEntry, pos, *, n_heads, n_kv_heads,
     mask = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)[:, None, None, :]
     if attn_impl == "pallas":
         from repro.kernels.decode_attention import ops as da_ops
-        out = da_ops.decode_attention(q[:, 0], k, v, valid, interpret=True)
+        out = da_ops.decode_attention(q[:, 0], k, v, valid)
         out = out[:, None]
     else:
         out = _sdpa(q, k.astype(q.dtype), v.astype(q.dtype), mask)
@@ -406,8 +397,7 @@ def paged_prefill_attention(p, x, kv: KVEntry, block_table, *, n_heads,
     mask = causal_mask(S, S)
     if attn_impl in ("pallas", "paged"):
         from repro.kernels.flash_attention import ops as fa_ops
-        out = fa_ops.flash_attention(q, k, v, causal=True, window=0,
-                                     interpret=True)
+        out = fa_ops.flash_attention(q, k, v, causal=True, window=0)
     else:
         out = _sdpa(q, k, v, mask)
     out = out.reshape(B, S, n_heads * head_dim)
@@ -542,8 +532,7 @@ def paged_decode_attention(p, x, kv: KVEntry, block_table, pos, *, wpage,
         out = pa_ops.paged_decode_attention(q[:, 0], kv.k, kv.v,
                                             block_table, lens,
                                             k_scales=kv.k_scale,
-                                            v_scales=kv.v_scale,
-                                            interpret=True)
+                                            v_scales=kv.v_scale)
         out = out[:, None]
     else:
         # gather + mask per kernels/paged_attention/ref.py (keep the
@@ -638,8 +627,7 @@ def spec_verify_chunk_attention(p, x, kv: KVEntry, block_table, pos, *,
         from repro.kernels.spec_verify import ops as sv_ops
         out = sv_ops.spec_verify_attention(q, kv.k, kv.v, block_table, pos,
                                            k_scales=kv.k_scale,
-                                           v_scales=kv.v_scale,
-                                           interpret=True)
+                                           v_scales=kv.v_scale)
     else:
         # gather + mask per kernels/spec_verify/ref.py; attend via _sdpa so
         # the fallback matches the single-token paged fallback's

@@ -165,7 +165,8 @@ def mamba_mixer(cfg: ModelConfig, p, x, *, initial_state=None,
                 attn_impl: str = "xla"):
     """Full-sequence Mamba2 mixer. x: (B,S,D) -> (y, final_state).
     attn_impl="pallas" routes the scan through the ssd_scan TPU kernel
-    (interpret mode on CPU); "xla" uses the pure-jnp chunked form."""
+    (interpreted on CPU, compiled on TPU); "xla" uses the pure-jnp chunked
+    form."""
     s, d_inner, conv_ch, _ = _dims(cfg)
     Bsz, S, _ = x.shape
     proj = jnp.einsum("bsd,de->bse", x, p["in_proj"])
@@ -180,8 +181,7 @@ def mamba_mixer(cfg: ModelConfig, p, x, *, initial_state=None,
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
     if attn_impl == "pallas" and initial_state is None:
         from repro.kernels.ssd_scan import ops as ssd_ops
-        y, final = ssd_ops.ssd_scan(xs, dt, A, Bmat, Cmat, s.chunk_size,
-                                    interpret=True)
+        y, final = ssd_ops.ssd_scan(xs, dt, A, Bmat, Cmat, s.chunk_size)
     else:
         y, final = ssd_chunked(xs, dt, A, Bmat, Cmat, s.chunk_size,
                                initial_state=initial_state)

@@ -430,7 +430,7 @@ class CompiledRolloutEngine:
             round-tripping through the scan carry between two ops."""
             from repro.kernels.fused_sample import ops as fs_ops
             tok, lp = fs_ops.fused_sample_tokens(
-                krng, logits, temperature, top_p=top_p, interpret=True)
+                krng, logits, temperature, top_p=top_p)
             (new_logits, new_cache), _ = decode((logits, cache),
                                                 (tok, write))
             return tok, lp, new_logits, new_cache
@@ -1084,12 +1084,12 @@ class CompiledRolloutEngine:
                           out_shardings=carry_sh, donate_argnums=(3,))
 
         def call_init(params, ref_params, draft_params, carry):
-            with mesh:                       # anchor layers.constrain
+            with jax.set_mesh(mesh):     # anchors layers.constrain
                 return jf_init(params, ref_params, draft_params, carry)
 
         def call_turn(params, ref_params, draft_params, carry, trng,
                       brng):
-            with mesh:
+            with jax.set_mesh(mesh):
                 return jf_turn(params, ref_params, draft_params, carry,
                                trng, brng)
 

@@ -45,10 +45,6 @@ H100 = HardwareSpec("h100-80g", peak_flops=989e12, hbm_bw=3.35e12,
                     link_bw=450e9, hbm_bytes=80e9,
                     coll_hop_latency=12e-6)
 
-PEAK_FLOPS_BF16 = V5E.peak_flops
-HBM_BW = V5E.hbm_bw
-ICI_BW_PER_LINK = V5E.link_bw
-
 
 @dataclass
 class RooflineReport:
@@ -58,9 +54,9 @@ class RooflineReport:
     hlo_bytes: float            # per-chip HBM bytes from cost_analysis
     collective_bytes: float     # per-chip collective bytes from HLO parse
     model_flops: float          # analytic 6*N*D (or 6*N_active*D) global
+    hw: HardwareSpec            # the chip whose peaks score the program
     collective_count: float = 0.0   # trip-weighted collective op count
     ring_size: int = 1              # hops per collective (latency model)
-    hw: "HardwareSpec" = None
     compute_s: float = 0.0
     memory_s: float = 0.0
     collective_s: float = 0.0
@@ -69,7 +65,7 @@ class RooflineReport:
     peak_memory_bytes: float = 0.0
 
     def finalize(self) -> "RooflineReport":
-        hw = self.hw or V5E
+        hw = self.hw
         self.compute_s = self.hlo_flops / hw.peak_flops
         self.memory_s = self.hlo_bytes / hw.hbm_bw
         # bandwidth term + per-op latency floor (rings serialize hops)
@@ -99,9 +95,10 @@ class RooflineReport:
 
 
 def analyze(name, *, chips, cost_analysis, collective_bytes, model_flops,
-            peak_memory_bytes=0.0, collective_count=0.0, ring_size=1,
-            hw=None) -> RooflineReport:
-    """Build a RooflineReport from a compiled program's analyses.
+            hw: HardwareSpec, peak_memory_bytes=0.0, collective_count=0.0,
+            ring_size=1) -> RooflineReport:
+    """Build a RooflineReport from a compiled program's analyses, scored
+    against the peaks of ``hw``.
 
     cost_analysis: the dict from ``compiled.cost_analysis()``.
     collective_bytes: from ``repro.utils.hlo.collective_bytes(...)``.

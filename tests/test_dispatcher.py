@@ -23,7 +23,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_subprocess(code: str, devices: int = 8) -> str:
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(REPO, "src"))
+               PYTHONPATH=os.path.join(REPO, "src"), JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, timeout=600)
     assert out.returncode == 0, out.stderr[-4000:]

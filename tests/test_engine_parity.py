@@ -103,10 +103,41 @@ class TestSlotRefill:
         assert stats.episodes_returned == N
         assert exp.batch == N
         ctx = np.asarray(exp.context_len)
-        # every episode row was actually written by the harvest scatter
+        # every episode row was actually written by the harvest
         assert (ctx > 0).all()
         # each harvested episode carries at least its initial observation
         assert (ctx >= env.obs_len).all()
+
+    def test_harvest_matches_row_copy(self):
+        """``slots.harvest`` writes each finished slot's row to its
+        episode's store row, bit for bit, and leaves every other store row
+        as it was."""
+        from repro.rl.engine import slots
+        N, T, B, turns = 6, 10, 4, 3
+        rs = np.random.RandomState(0)
+        store = slots.init_store(N, T, turns)
+        store = store._replace(logprobs=store.logprobs + 7.0)   # old rows
+        finished = np.array([True, False, True, True])
+        episode = np.array([4, 1, 0, 5], np.int32)
+        rows = dict(tokens=rs.randint(0, 99, (B, T)).astype(np.int32),
+                    gen_mask=rs.rand(B, T) < 0.5,
+                    logprobs=-rs.rand(B, T).astype(np.float32),
+                    ref_logprobs=-rs.rand(B, T).astype(np.float32),
+                    rewards=rs.randn(B).astype(np.float32),
+                    pos=rs.randint(1, T, B).astype(np.int32),
+                    truncated=rs.rand(B) < 0.5,
+                    n_turns=rs.randint(1, turns, B).astype(np.int32),
+                    turn_lengths=rs.randint(0, 5, (B, turns)).astype(
+                        np.int32))
+        out = jax.jit(slots.harvest)(store, finished=finished,
+                                     episode=episode, **rows)
+        field = dict(pos="context_len")
+        for name, row in rows.items():
+            want = np.array(getattr(store, field.get(name, name)))
+            want[episode[finished]] = row[finished]
+            np.testing.assert_array_equal(
+                np.asarray(getattr(out, field.get(name, name))), want,
+                err_msg=name)
 
     def test_single_turn_env_max_churn(self, model_and_params):
         """Bandit episodes end every turn — every macro-step refills every

@@ -514,3 +514,36 @@ def test_ring_kv_cache_matches_full_cache_window(rng):
     # beyond W steps: ring logits still finite and cache pos tracks t
     assert bool(jnp.isfinite(lw.astype(jnp.float32)).all())
     assert int(cache_w.pos[0]) == total
+
+
+@pytest.mark.parametrize("name", [
+    "flash_fwd", "flash_bwd", "decode", "paged_bf16", "paged_int8",
+    "spec_verify", "fused_sample", "ssd_scan"])
+def test_kernel_case_matches_oracle_at_smoke_widths(name, rng):
+    """The kernel-versus-oracle table that the on-chip smoke check runs at
+    full width, run here in interpret mode at the smoke configs' widths."""
+    from repro.configs.base import get_smoke_config
+    from repro.kernels.cases import kernel_cases, max_error
+    cases = {c.name: c for c in kernel_cases(
+        get_smoke_config("qwen2-0.5b"), get_smoke_config("mamba2-370m"),
+        batch=2, seq=128, page_size=16)}
+    case = cases[name]
+    args = case.args(rng)
+    err = max_error(case.kernel(*args, interpret=True), case.ref(*args))
+    assert err <= case.tol, (name, err)
+
+
+@pytest.mark.parametrize("backend,expect", [
+    ("cpu", True), ("tpu", False), ("gpu", RuntimeError)])
+def test_interpret_mode_follows_backend(backend, expect, monkeypatch):
+    """Kernels interpret on CPU, compile on TPU, and refuse anything else
+    rather than silently falling back to the interpreter."""
+    from repro import kernels
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: backend)
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError, match=backend):
+            kernels.default_interpret()
+    else:
+        assert kernels.default_interpret() is expect
+        assert kernels.resolve_interpret(None) is expect
+        assert kernels.resolve_interpret(not expect) is (not expect)

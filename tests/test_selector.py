@@ -191,12 +191,13 @@ class TestCostModelMeasureIntegration:
             jf = jax.jit(make_prefill_step(model),
                          in_shardings=(p_sh, t_sh, c_sh),
                          donate_argnums=(2,))
-            with mesh:
+            with jax.set_mesh(mesh):
                 return jf.lower(params, toks, cache)
 
         candidates = [MeshConfig('tp2', dp=4, tp=2),
                       MeshConfig('tp4', dp=2, tp=4)]
-        measure = make_cost_model_measure(lower_fn)
+        from repro.utils.roofline import V5E
+        measure = make_cost_model_measure(lower_fn, hw=V5E)
         sel = ParallelismSelector(candidates, measure,
                                   ContextBuckets((64,)))
         pol = sel.profile()
