@@ -202,18 +202,17 @@ class PipelineSchedule:
             rng_after_k = tr._rng
 
             def work():
-                t0 = time.perf_counter()
                 handle = None
                 tr.check_fault("dispatch", k)
                 if dst_shardings is not None:
                     exp_d, handle = tr.dispatch_stage(
                         exp, dst_shardings, src_shardings=src_shardings,
-                        asynchronous=True)
+                        asynchronous=True, step=k)
                 else:
                     exp_d = exp
                 tr.check_fault("update", k)
                 p, o = state["params"], state["opt_state"]
-                p2, o2, metrics = tr.update_stage(p, o, exp_d)
+                p2, o2, metrics = tr.update_stage(p, o, exp_d, step=k)
                 state["params"], state["opt_state"] = p2, o2
                 # checkpoint inside the worker: the single-worker pool
                 # serializes this with the NEXT update, so the saved
@@ -227,23 +226,20 @@ class PipelineSchedule:
                     # the transfer alone, not the overlapped compute
                     _, rep = handle.result()
                     dispatch_row = rep.row()
-                return (p2, o2, metrics, dispatch_row,
-                        time.perf_counter() - t0)
+                return p2, o2, metrics, dispatch_row
             futures[k] = pool.submit(work)
 
         def resolve(k):
             """Finalize step k: wait for its update, publish the new
             params version, record the step."""
-            p2, _, metrics, dispatch_row, upd_wall = \
-                futures.pop(k).result()
+            p2, _, metrics, dispatch_row = futures.pop(k).result()
             versions[k + 1] = p2
             row = pending.pop(k)
             rec = tr.make_record(
                 k, row["stats"], metrics, switch=row["switch"],
                 dispatch_row=dispatch_row,
                 wall_time_s=time.perf_counter() - row["t0"],
-                rollout_wall_s=row["rollout_wall_s"],
-                update_wall_s=upd_wall, policy_lag=row["policy_lag"])
+                policy_lag=row["policy_lag"])
             if verbose:
                 _print_record(rec)
 
@@ -277,7 +273,7 @@ class PipelineSchedule:
                     reuse_behavior_lp=(
                         ref_params is behavior and tr.top_p == 1.0
                         and (tr.temperature <= 0.0
-                             or tr.temperature == 1.0)))
+                             or tr.temperature == 1.0)), step=k)
                 # capture the engine-reported source layout NOW — the
                 # next rollout overwrites it before the worker runs
                 src = (tr.dispatch_stage.source_shardings(exp)
@@ -290,7 +286,6 @@ class PipelineSchedule:
                     tr.selector.maybe_switch(k, stage="update")
                 pending[k] = {
                     "stats": stats, "switch": switch, "t0": t0,
-                    "rollout_wall_s": time.perf_counter() - t0,
                     "policy_lag": k - v,
                 }
                 submit(pool, k, exp, src)

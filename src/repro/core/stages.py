@@ -33,7 +33,9 @@ place them on different meshes/threads; ``EarlTrainer`` wires the
 substrate (model, env, rollout engine, optimizer) into them and remains
 the user-facing driver. Every stage transition stays observable: per-step
 ``StepRecord`` captures context-length growth (Fig. 1), selector switches
-(Fig. 3), dispatch reports (Fig. 4), policy lag and paged-pool telemetry.
+(Fig. 3), dispatch reports (Fig. 4), policy lag and paged-pool telemetry;
+under a profiler session each step and each stage call is an ``earl.*``
+span carrying its step (``utils/tracing.py``).
 """
 from __future__ import annotations
 
@@ -55,6 +57,7 @@ from repro.rl.algo import reinforce_advantages, group_relative_advantages
 from repro.rl.engine import CompiledRolloutEngine
 from repro.rl.experience import ExperienceBatch
 from repro.rl.rollout import RolloutEngine, RolloutStats
+from repro.utils.tracing import span, to_host, watch_gc
 
 
 @dataclass
@@ -73,8 +76,6 @@ class StepRecord:
     # and how stale it was relative to the synchronous schedule
     params_version: int = -1
     policy_lag: int = 0
-    rollout_wall_s: float = 0.0
-    update_wall_s: float = 0.0
     is_weight_mean: float = 0.0          # truncated-IS mean (1.0 on-policy)
     # paged-pool telemetry (ROADMAP: exhaustion must not be silent)
     pages_in_use: int = 0
@@ -132,10 +133,11 @@ class RolloutStage:
                     and self.engine.mesh_config != cur):
                 self.engine.bind_mesh(cur)
 
-        exp, stats = self.engine.run(params, rng, batch,
-                                     n_episodes=n_episodes,
-                                     ref_params=ref_params,
-                                     params_version=params_version)
+        with span("rollout", step=step):
+            exp, stats = self.engine.run(params, rng, batch,
+                                         n_episodes=n_episodes,
+                                         ref_params=ref_params,
+                                         params_version=params_version)
         if sel is not None:
             sel.observe(stats.mean_context_len)
         return exp, stats, switch
@@ -160,32 +162,34 @@ class ExpPrepStage:
         self._logged_lp_reuse = False
 
     def __call__(self, exp: ExperienceBatch, *, ref_params=None,
-                 ref_folded: bool = True,
-                 reuse_behavior_lp: bool = False) -> ExperienceBatch:
-        if ref_params is not None and not ref_folded:
-            if reuse_behavior_lp:
-                # fast path: the reference IS the params that generated
-                # the rollout (lag-1 snapshot) and sampling was unbiased,
-                # so the behavior log-probs the engine already recorded
-                # ARE the reference log-probs at every loss position
-                # (loss_mask == gen_mask; obs positions are never read by
-                # the KL term) — skip the second full-model evaluation
-                if not self._logged_lp_reuse:
-                    self._logged_lp_reuse = True
-                    logging.getLogger(__name__).info(
-                        "ExpPrepStage: reference == behavior params — "
-                        "reusing rollout log-probs for the ref pass "
-                        "(standalone ref forward pass skipped)")
-                exp = exp.with_(ref_logprobs=jnp.where(
-                    exp.gen_mask, exp.logprobs, 0.0))
+                 ref_folded: bool = True, reuse_behavior_lp: bool = False,
+                 step: int = -1) -> ExperienceBatch:
+        with span("expprep", step=step):
+            if ref_params is not None and not ref_folded:
+                if reuse_behavior_lp:
+                    # fast path: the reference IS the params that generated
+                    # the rollout (lag-1 snapshot) and sampling was unbiased,
+                    # so the behavior log-probs the engine already recorded
+                    # ARE the reference log-probs at every loss position
+                    # (loss_mask == gen_mask; obs positions are never read by
+                    # the KL term) — skip the second full-model evaluation
+                    if not self._logged_lp_reuse:
+                        self._logged_lp_reuse = True
+                        logging.getLogger(__name__).info(
+                            "ExpPrepStage: reference == behavior params — "
+                            "reusing rollout log-probs for the ref pass "
+                            "(standalone ref forward pass skipped)")
+                    exp = exp.with_(ref_logprobs=jnp.where(
+                        exp.gen_mask, exp.logprobs, 0.0))
+                else:
+                    with span("ref"):
+                        exp = exp.with_(ref_logprobs=self._ref_step(
+                            ref_params, exp.tokens))
+            if self.advantage == "group":
+                adv = group_relative_advantages(exp.rewards, self.group_size)
             else:
-                exp = exp.with_(ref_logprobs=self._ref_step(ref_params,
-                                                            exp.tokens))
-        if self.advantage == "group":
-            adv = group_relative_advantages(exp.rewards, self.group_size)
-        else:
-            adv = reinforce_advantages(exp.rewards)
-        return exp.with_(advantages=adv)
+                adv = reinforce_advantages(exp.rewards)
+            return exp.with_(advantages=adv)
 
 
 class DispatchStage:
@@ -215,22 +219,24 @@ class DispatchStage:
                             advantages=exp.advantages.sharding)
 
     def __call__(self, exp: ExperienceBatch, dst_shardings, *,
-                 src_shardings=None, asynchronous: bool = False):
+                 src_shardings=None, asynchronous: bool = False,
+                 step: int = -1):
         """Returns ``(exp, report_row_or_handle)``; (exp, None) when no
         dst_shardings were requested."""
-        if dst_shardings is None:
-            return exp, None
-        if src_shardings is None:
-            src_shardings = self.source_shardings(exp)
-        if asynchronous:
-            handle = self.dispatcher.dispatch_async(
+        with span("dispatch", step=step):
+            if dst_shardings is None:
+                return exp, None
+            if src_shardings is None:
+                src_shardings = self.source_shardings(exp)
+            if asynchronous:
+                handle = self.dispatcher.dispatch_async(
+                    exp, dst_shardings, strategy=self.strategy,
+                    src_shardings=src_shardings)
+                return handle.batch, handle
+            exp, rep = self.dispatcher.dispatch(
                 exp, dst_shardings, strategy=self.strategy,
                 src_shardings=src_shardings)
-            return handle.batch, handle
-        exp, rep = self.dispatcher.dispatch(
-            exp, dst_shardings, strategy=self.strategy,
-            src_shardings=src_shardings)
-        return exp, rep.row()
+            return exp, rep.row()
 
 
 class UpdateStage:
@@ -252,8 +258,10 @@ class UpdateStage:
                                kl_coef=kl_coef, is_rho_max=is_rho_max),
             donate_argnums=(1,))
 
-    def __call__(self, params, opt_state, exp: ExperienceBatch):
-        return self._step(params, opt_state, exp)
+    def __call__(self, params, opt_state, exp: ExperienceBatch, *,
+                 step: int = -1):
+        with span("update", step=step):
+            return self._step(params, opt_state, exp)
 
 
 # ---------------------------------------------------------------------------
@@ -408,6 +416,7 @@ class EarlTrainer:
             self.model, self.optimizer, clip_eps=self.clip_eps,
             kl_coef=self.kl_coef, is_rho_max=self.is_rho_max)
         self._rng = jax.random.PRNGKey(self.seed)
+        watch_gc()
 
         # injected pool pressure: undersize the paged pool to a fraction
         # of the exhaustion-free provisioning, clamped to the preemption
@@ -456,7 +465,6 @@ class EarlTrainer:
 
     def make_record(self, step: int, stats: RolloutStats, metrics,
                     *, switch=None, dispatch_row=None, wall_time_s=0.0,
-                    rollout_wall_s=0.0, update_wall_s=0.0,
                     policy_lag: int = 0) -> StepRecord:
         """Assemble the per-step observability row (shared by the sync
         path and the async scheduler)."""
@@ -466,16 +474,15 @@ class EarlTrainer:
             mean_context_len=stats.mean_context_len,
             mean_turn_len=stats.mean_turn_len,
             truncated_frac=float(np.mean(stats.truncated)),
-            loss=float(metrics["loss"]),
-            kl=float(metrics.get("kl", 0.0)),
+            loss=float(to_host(metrics["loss"], "loss")),
+            kl=float(to_host(metrics.get("kl", 0.0), "kl")),
             selector_switch=switch,
             dispatch=dispatch_row,
             wall_time_s=wall_time_s,
             params_version=stats.params_version,
             policy_lag=policy_lag,
-            rollout_wall_s=rollout_wall_s,
-            update_wall_s=update_wall_s,
-            is_weight_mean=float(metrics.get("is_weight_mean", 0.0)),
+            is_weight_mean=float(to_host(
+                metrics.get("is_weight_mean", 0.0), "is_weight_mean")),
             pages_in_use=stats.pages_in_use,
             page_capacity=stats.page_capacity,
             kv_dropped_writes=stats.kv_dropped_writes,
@@ -523,54 +530,52 @@ class EarlTrainer:
                  dst_shardings=None):
         """One full Fig. 2 iteration, synchronously: Rollout → ExpPrep →
         Dispatch → Update. Returns (params, opt_state, record)."""
-        self._maybe_warn_ref_fallback(ref_params)
-        t0 = time.perf_counter()
+        with span("step", step=step):
+            self._maybe_warn_ref_fallback(ref_params)
+            t0 = time.perf_counter()
 
-        # ① Rollout (+ folded ref pass). Both engines share the run
-        # signature; n_episodes > batch_size engages slot refill.
-        self.check_fault("rollout", step)
-        exp, stats, switch = self.rollout_stage(
-            step, params, self._next_rng(), self.batch_size,
-            n_episodes=self.rollout_episodes,
-            ref_params=ref_params if self.ref_folded else None,
-            params_version=step)
-        t_roll = time.perf_counter() - t0
+            # ① Rollout (+ folded ref pass). Both engines share the run
+            # signature; n_episodes > batch_size engages slot refill.
+            self.check_fault("rollout", step)
+            exp, stats, switch = self.rollout_stage(
+                step, params, self._next_rng(), self.batch_size,
+                n_episodes=self.rollout_episodes,
+                ref_params=ref_params if self.ref_folded else None,
+                params_version=step)
 
-        # ② Experience Preparation (advantages; ref folded into the
-        # rollout unless prefix sharing / speculation forced the
-        # standalone fallback — which itself is skipped when the
-        # reference IS the behavior params and sampling recorded
-        # unbiased model log-probs: temperature 1 or greedy, top_p off)
-        reuse_lp = (ref_params is params and self.top_p == 1.0
-                    and (self.temperature <= 0.0
-                         or self.temperature == 1.0))
-        exp = self.expprep_stage(exp, ref_params=ref_params,
-                                 ref_folded=self.ref_folded,
-                                 reuse_behavior_lp=reuse_lp)
+            # ② Experience Preparation (advantages; ref folded into the
+            # rollout unless prefix sharing / speculation forced the
+            # standalone fallback — which itself is skipped when the
+            # reference IS the behavior params and sampling recorded
+            # unbiased model log-probs: temperature 1 or greedy, top_p off)
+            reuse_lp = (ref_params is params and self.top_p == 1.0
+                        and (self.temperature <= 0.0
+                             or self.temperature == 1.0))
+            exp = self.expprep_stage(exp, ref_params=ref_params,
+                                     ref_folded=self.ref_folded,
+                                     reuse_behavior_lp=reuse_lp, step=step)
 
-        # ③④⑤ Dispatch to the Update layout
-        self.check_fault("dispatch", step)
-        exp, dispatch_row = self.dispatch_stage(exp, dst_shardings)
+            # ③④⑤ Dispatch to the Update layout
+            self.check_fault("dispatch", step)
+            exp, dispatch_row = self.dispatch_stage(exp, dst_shardings,
+                                                    step=step)
 
-        # Model Update. The selector's update-stage config is *tracked*
-        # independently of the rollout stage's (the async schedule needs
-        # both alive at once); today it is bookkeeping/switch-log only —
-        # the update program is a single jit that GSPMD places from its
-        # input shardings, and rebinding it per MeshConfig is the
-        # ROADMAP submesh-split follow-on.
-        if self.selector is not None and self.selector.policy is not None:
-            self.selector.maybe_switch(step, stage="update")
-        t1 = time.perf_counter()
-        self.check_fault("update", step)
-        params, opt_state, metrics = self.update_stage(params, opt_state,
-                                                       exp)
-        loss = float(metrics["loss"])        # blocks: sync schedule
-        del loss
-        rec = self.make_record(
-            step, stats, metrics, switch=switch, dispatch_row=dispatch_row,
-            wall_time_s=time.perf_counter() - t0, rollout_wall_s=t_roll,
-            update_wall_s=time.perf_counter() - t1, policy_lag=0)
-        return params, opt_state, rec
+            # Model Update. The selector's update-stage config is *tracked*
+            # independently of the rollout stage's (the async schedule needs
+            # both alive at once); today it is bookkeeping/switch-log only —
+            # the update program is a single jit that GSPMD places from its
+            # input shardings, and rebinding it per MeshConfig is the
+            # ROADMAP submesh-split follow-on.
+            if self.selector is not None and self.selector.policy is not None:
+                self.selector.maybe_switch(step, stage="update")
+            self.check_fault("update", step)
+            params, opt_state, metrics = self.update_stage(params, opt_state,
+                                                           exp, step=step)
+            # the record's reads of the metrics block: sync schedule
+            rec = self.make_record(step, stats, metrics, switch=switch,
+                                   dispatch_row=dispatch_row, policy_lag=0)
+            rec.wall_time_s = time.perf_counter() - t0
+            return params, opt_state, rec
 
     # ------------------------------------------------------------------
     def train(self, n_steps: int, *, params=None, opt_state=None,

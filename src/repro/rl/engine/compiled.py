@@ -78,6 +78,7 @@ from repro.rl.engine import common, paging, slots
 from repro.rl.engine.common import ACTION_BASE
 from repro.rl.envs.base import TOK_PAD, default_reset_rows
 from repro.rl.experience import ExperienceBatch
+from repro.utils.tracing import span, to_host
 
 
 def _reset_cache_rows(cache, refill):
@@ -1283,8 +1284,9 @@ class CompiledRolloutEngine:
                 f"zero-drop guarantee cannot hold.")
 
         init_fn, turn_fn = self._get_compiled(B, N, with_ref)
-        carry = init_fn(params, ref_params, draft_params,
-                        self._init_carry(rng, B, N, with_ref))
+        with span("rollout.init", rows=B):
+            carry = init_fn(params, ref_params, draft_params,
+                            self._init_carry(rng, B, N, with_ref))
         base = jax.random.fold_in(rng, 1)
         brng = jax.random.fold_in(rng, 2)
 
@@ -1310,12 +1312,14 @@ class CompiledRolloutEngine:
                                                self.page_size))
             last_dropped = last_preempted = 0
         for m in range(max_macro):
-            carry = turn_fn(params, ref_params, draft_params, carry,
-                            common.turn_rng(base, m), brng)
+            with span("rollout.turn", turn=m):
+                carry = turn_fn(params, ref_params, draft_params, carry,
+                                common.turn_rng(base, m), brng)
             # ONE host sync per turn (the returned-counter read); the
             # on_exhaust="raise" drop check and the pool-growth trigger
             # ride the same sync point
-            if check_drops and int(carry.kv_dropped) > 0:
+            if check_drops and int(to_host(carry.kv_dropped,
+                                           "kv_dropped")) > 0:
                 short = np.asarray(carry.kv_shortfall)
                 bad = np.nonzero(short > 0)[0]
                 detail = ", ".join(
@@ -1341,10 +1345,12 @@ class CompiledRolloutEngine:
                 # host-side pad of zeroed free pages between macro-steps;
                 # jit retraces for the new capacity (cached per shape).
                 cap = int(carry.cache.refcount.shape[0])
-                dropped = int(carry.kv_dropped)
-                pre = int(carry.preempted) if preempt else 0
-                free = int(jnp.sum(
-                    (carry.cache.refcount == 0).astype(jnp.int32)))
+                dropped = int(to_host(carry.kv_dropped, "kv_dropped"))
+                pre = (int(to_host(carry.preempted, "preempted"))
+                       if preempt else 0)
+                free = int(to_host(jnp.sum(
+                    (carry.cache.refcount == 0).astype(jnp.int32)),
+                    "free_pages"))
                 if cap < grow_cap and (
                         dropped > last_dropped or pre > last_preempted
                         or free < self.admit_watermark):
@@ -1352,7 +1358,7 @@ class CompiledRolloutEngine:
                         carry.cache, min(2 * cap, grow_cap)))
                     pool_grows += 1
                 last_dropped, last_preempted = dropped, pre
-            if int(carry.returned) >= N:
+            if int(to_host(carry.returned, "returned")) >= N:
                 break
         if preempt and int(carry.returned) < N:
             raise RuntimeError(
@@ -1388,25 +1394,27 @@ class CompiledRolloutEngine:
         self.experience_shardings = ExperienceBatch(
             *(x.sharding for x in exp))
         paged = paging.is_paged(carry.cache)
-        stats = common.summarize(
-            store.turn_lengths, store.context_len, store.n_turns,
-            store.truncated, store.rewards,
-            episodes_started=int(carry.launched),
-            episodes_returned=int(carry.returned),
-            params_version=params_version,
-            pages_in_use=int(carry.pages_peak),
-            page_capacity=carry.cache.refcount.shape[0] if paged else 0,
-            kv_dropped_writes=int(carry.kv_dropped),
-            shared_prefix_len=self.shared_len,
-            preemptions=(int(carry.preempted)
-                         if carry.preempted is not None else 0),
-            requeue_depth=(int(carry.requeue_peak)
-                           if carry.requeue_peak is not None else 0),
-            pool_grows=int(pool_grows),
-            spec_proposed=(int(carry.spec_proposed)
-                           if carry.spec_proposed is not None else 0),
-            spec_accepted=(int(carry.spec_accepted)
-                           if carry.spec_accepted is not None else 0),
-            spec_rounds=(int(carry.spec_rounds)
-                         if carry.spec_rounds is not None else 0))
+        # the harvest's scalar and per-episode reads: one sync point
+        with span("sync", what="stats"):
+            stats = common.summarize(
+                store.turn_lengths, store.context_len, store.n_turns,
+                store.truncated, store.rewards,
+                episodes_started=int(carry.launched),
+                episodes_returned=int(carry.returned),
+                params_version=params_version,
+                pages_in_use=int(carry.pages_peak),
+                page_capacity=carry.cache.refcount.shape[0] if paged else 0,
+                kv_dropped_writes=int(carry.kv_dropped),
+                shared_prefix_len=self.shared_len,
+                preemptions=(int(carry.preempted)
+                             if carry.preempted is not None else 0),
+                requeue_depth=(int(carry.requeue_peak)
+                               if carry.requeue_peak is not None else 0),
+                pool_grows=int(pool_grows),
+                spec_proposed=(int(carry.spec_proposed)
+                               if carry.spec_proposed is not None else 0),
+                spec_accepted=(int(carry.spec_accepted)
+                               if carry.spec_accepted is not None else 0),
+                spec_rounds=(int(carry.spec_rounds)
+                             if carry.spec_rounds is not None else 0))
         return exp, stats

@@ -14,7 +14,9 @@ The action protocol, sampling, rng derivation and stats live in
 (``rl/engine/compiled.py``). This per-token python loop is the
 CPU-friendly reference path: it host-syncs on every token, which is
 exactly the overhead the compiled engine removes; a parity test pins both
-engines to identical greedy trajectories.
+engines to identical greedy trajectories. Under a profiler session each
+phase shows as an ``earl.rollout.*`` span and each host read as an
+``earl.sync`` span (``utils/tracing.py``).
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ from repro.rl.engine import common
 from repro.rl.engine.common import ACTION_BASE, RolloutStats  # re-exported
 from repro.rl.envs.base import TOK_PAD
 from repro.rl.experience import ExperienceBatch
+from repro.utils.tracing import span, to_host
 
 
 @dataclass
@@ -44,11 +47,18 @@ class RolloutEngine:
     def __post_init__(self):
         cfg = self.model.cfg
         assert ACTION_BASE + self.env.n_actions <= cfg.vocab_size
-        self._decode = jax.jit(
-            lambda p, tok, cache, adv: self.model.decode_step(
-                p, tok, cache, advance=adv))
-        self._prefill = jax.jit(
-            lambda p, toks, cache: self.model.prefill(p, toks, cache))
+        model = self.model
+
+        # named, so their device ops read jit_rollout_decode/... and
+        # jit_rollout_prefill/... in a profile
+        def rollout_decode(p, tok, cache, adv):
+            return model.decode_step(p, tok, cache, advance=adv)
+
+        def rollout_prefill(p, toks, cache):
+            return model.prefill(p, toks, cache)
+
+        self._decode = jax.jit(rollout_decode)
+        self._prefill = jax.jit(rollout_prefill)
 
         # reference-model pass for signature parity with the compiled
         # engine's in-graph fold; the python path is the semantic
@@ -75,8 +85,9 @@ class RolloutEngine:
         T = self.max_context
         B = batch
 
-        state = env.reset(rng, B)
-        obs = env.encode_obs(state)                       # (B, obs_len)
+        with span("rollout.reset", rows=B):
+            state = env.reset(rng, B)
+            obs = to_host(env.encode_obs(state), "obs")   # (B, obs_len)
 
         tokens = np.full((B, T), TOK_PAD, np.int32)
         gen_mask = np.zeros((B, T), bool)
@@ -85,26 +96,27 @@ class RolloutEngine:
         n_turns = np.zeros(B, np.int32)
         truncated = np.zeros(B, bool)
 
-        obs_np = np.asarray(obs)
-        olen = obs_np.shape[1]
-        tokens[:, :olen] = obs_np
+        olen = obs.shape[1]
+        tokens[:, :olen] = obs
         pos = np.full(B, olen, np.int32)                  # per-row write ptr
 
-        cache = model.init_cache(B, T)
-        logits_buf, cache = self._prefill(
-            params, jnp.asarray(tokens[:, :olen]), cache)
+        with span("rollout.prefill", rows=B):
+            cache = model.init_cache(B, T)
+            logits_buf, cache = self._prefill(
+                params, jnp.asarray(tokens[:, :olen]), cache)
         done = np.zeros(B, bool)
         base_rng = jax.random.fold_in(rng, 1)
 
         def advance_rows(fed_tokens, mask):
             """Feed per-row tokens; only ``mask`` rows advance."""
             nonlocal logits_buf, cache
-            new_logits, cache2 = self._decode(
-                params, jnp.asarray(fed_tokens), cache,
-                jnp.asarray(mask))
-            logits_buf = jnp.where(jnp.asarray(mask)[:, None], new_logits,
-                                   logits_buf)
-            cache = cache2
+            with span("rollout.decode", rows=B, live=int(mask.sum())):
+                new_logits, cache2 = self._decode(
+                    params, jnp.asarray(fed_tokens), cache,
+                    jnp.asarray(mask))
+                logits_buf = jnp.where(jnp.asarray(mask)[:, None],
+                                       new_logits, logits_buf)
+                cache = cache2
 
         for turn in range(self.max_turns):
             if done.all():
@@ -121,60 +133,67 @@ class RolloutEngine:
             acted = waiting.copy()
             actions = np.zeros(B, np.int32)
             last_tok = np.zeros(B, np.int32)
-            for t in range(self.max_turn_tokens):
-                write = ~acted
-                if not write.any():
-                    break
-                sampled, lp = common.sample_tokens(
-                    common.sample_rng(trng, t), logits_buf,
-                    self.temperature, self.top_p)
-                sampled_np = np.asarray(sampled, np.int32)
-                lp_np = np.asarray(lp, np.float32)
+            with span("rollout.generate", turn=turn, rows=int(active.sum())):
+                for t in range(self.max_turn_tokens):
+                    write = ~acted
+                    if not write.any():
+                        break
+                    with span("rollout.sample"):
+                        sampled, lp = common.sample_tokens(
+                            common.sample_rng(trng, t), logits_buf,
+                            self.temperature, self.top_p)
+                        sampled_np = to_host(sampled, "sampled", np.int32)
+                        lp_np = to_host(lp, "logprob", np.float32)
 
-                rows = np.nonzero(write)[0]
-                tokens[rows, pos[rows]] = sampled_np[rows]
-                gen_mask[rows, pos[rows]] = True
-                logprobs[rows, pos[rows]] = lp_np[rows]
-                pos[rows] += 1
-                turn_lengths[rows, turn] += 1
-                last_tok[rows] = sampled_np[rows]
+                        rows = np.nonzero(write)[0]
+                        tokens[rows, pos[rows]] = sampled_np[rows]
+                        gen_mask[rows, pos[rows]] = True
+                        logprobs[rows, pos[rows]] = lp_np[rows]
+                        pos[rows] += 1
+                        turn_lengths[rows, turn] += 1
+                        last_tok[rows] = sampled_np[rows]
 
-                is_action = np.asarray(
-                    common.action_mask(sampled_np, env.n_actions))
-                newly = write & is_action
-                actions[newly] = sampled_np[newly] - ACTION_BASE
-                acted |= newly
+                        is_action = to_host(
+                            common.action_mask(sampled_np, env.n_actions),
+                            "action_mask")
+                        newly = write & is_action
+                        actions[newly] = sampled_np[newly] - ACTION_BASE
+                        acted |= newly
 
-                advance_rows(sampled_np, write)
+                    advance_rows(sampled_np, write)
 
-            # fallback action for rows that never emitted an action token
-            actions = np.asarray(common.fallback_actions(
-                actions, last_tok, active, acted, env.n_actions), np.int32)
-            n_turns[active] += 1
+            with span("rollout.env"):
+                # fallback action for rows that never emitted an action
+                actions = to_host(common.fallback_actions(
+                    actions, last_tok, active, acted, env.n_actions),
+                    "actions", np.int32)
+                n_turns[active] += 1
 
-            # env transition (inactive rows absorb inside env.step)
-            env_actions = np.where(active, actions, 0).astype(np.int32)
-            # freeze finished rows by making their action a no-op via done
-            state, res = env.step(state, jnp.asarray(env_actions),
-                                  common.env_rng(trng))
-            res_obs = np.asarray(res.obs_tokens)
-            new_done = np.asarray(res.done)
+                # env transition (inactive rows absorb inside env.step)
+                env_actions = np.where(active, actions, 0).astype(np.int32)
+                # freeze finished rows by making their action a no-op via
+                # done
+                state, res = env.step(state, jnp.asarray(env_actions),
+                                      common.env_rng(trng))
+                res_obs = to_host(res.obs_tokens, "obs")
+                new_done = to_host(res.done, "done")
 
             # teacher-force the observation for still-running rows; rows
             # out of turn budget skip it (no generation can follow — the
             # trailing obs would only burn context and decode steps)
             feed = active & ~new_done
             if turn + 1 < self.max_turns and feed.any():
-                for j in range(olen):
-                    col_tok = np.where(feed, res_obs[:, j],
-                                       TOK_PAD).astype(np.int32)
-                    rows = np.nonzero(feed)[0]
-                    tokens[rows, pos[rows]] = col_tok[rows]
-                    pos[rows] += 1
-                    advance_rows(col_tok, feed)
+                with span("rollout.observe", tokens=olen):
+                    for j in range(olen):
+                        col_tok = np.where(feed, res_obs[:, j],
+                                           TOK_PAD).astype(np.int32)
+                        rows = np.nonzero(feed)[0]
+                        tokens[rows, pos[rows]] = col_tok[rows]
+                        pos[rows] += 1
+                        advance_rows(col_tok, feed)
             done |= new_done | truncated
 
-        rewards = np.asarray(state.reward, np.float32)
+        rewards = to_host(state.reward, "reward", np.float32)
         # truncated episodes: zero reward (the Fig. 1 "low-quality data")
         rewards = np.where(truncated, 0.0, rewards)
 
@@ -184,9 +203,11 @@ class RolloutEngine:
             # positions 1..pos-1, zero elsewhere (PAD tail excluded)
             fed = ((np.arange(T)[None, :] >= 1)
                    & (np.arange(T)[None, :] < pos[:, None]))
-            ref_logprobs = jnp.asarray(np.where(
-                fed, np.asarray(self._ref_lp(ref_params,
-                                             jnp.asarray(tokens))), 0.0))
+            with span("ref"):
+                ref_lp = to_host(self._ref_lp(ref_params,
+                                              jnp.asarray(tokens)),
+                                 "ref_logprob")
+            ref_logprobs = jnp.asarray(np.where(fed, ref_lp, 0.0))
 
         exp = ExperienceBatch(
             tokens=jnp.asarray(tokens),
