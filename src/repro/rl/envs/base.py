@@ -14,7 +14,9 @@ n_actions``). Rewards: win=+1, draw=0, loss=-1, illegal move=-1 (terminal).
 
 Compiled-engine protocol: an env declares ``jit_safe = True`` when its
 ``reset`` / ``step`` / ``encode_obs`` are pure ``jnp`` (traceable inside
-``jax.jit``), and provides ``reset_rows(rng, state, mask)`` — a pure
+``jax.jit``); the python engine then also compiles its per-turn env
+transition (``jit_rollout_env``) instead of running ``step`` op by op.
+A jit-safe env provides ``reset_rows(rng, state, mask)`` — a pure
 row-wise reset used for in-graph slot refill (``default_reset_rows``
 below covers any env with batch-leading state leaves). Optionally it
 declares ``prompt_prefix_len``: the number of LEADING tokens of every

@@ -14,9 +14,13 @@ The action protocol, sampling, rng derivation and stats live in
 (``rl/engine/compiled.py``). This per-token python loop is the
 CPU-friendly reference path: it host-syncs on every token, which is
 exactly the overhead the compiled engine removes; a parity test pins both
-engines to identical greedy trajectories. Under a profiler session each
-phase shows as an ``earl.rollout.*`` span and each host read as an
-``earl.sync`` span (``utils/tracing.py``).
+engines to identical greedy trajectories. It does not dispatch op by op:
+each sampled token runs one program (``jit_rollout_sample``: key, sample,
+log-prob, action mask) and each turn's env transition another
+(``jit_rollout_env``: fallback actions, env step), the latter only for an
+env that declares ``jit_safe`` (otherwise the same function runs eagerly).
+Under a profiler session each phase shows as an ``earl.rollout.*`` span
+and each host read as an ``earl.sync`` span (``utils/tracing.py``).
 """
 from __future__ import annotations
 
@@ -59,6 +63,29 @@ class RolloutEngine:
 
         self._decode = jax.jit(rollout_decode)
         self._prefill = jax.jit(rollout_prefill)
+
+        env = self.env
+        temperature, top_p = self.temperature, self.top_p
+
+        def rollout_env(state, actions, last_tok, active, acted, trng):
+            # fallback action for rows that never emitted an action; rows
+            # not taking the turn absorb inside env.step (action 0)
+            actions = common.fallback_actions(actions, last_tok, active,
+                                              acted, env.n_actions)
+            env_actions = jnp.where(active, actions, 0).astype(jnp.int32)
+            state, res = env.step(state, env_actions, common.env_rng(trng))
+            return state, actions, res.obs_tokens, res.done
+
+        def rollout_sample(logits, trng, t):
+            tok, lp = common.sample_tokens(common.sample_rng(trng, t),
+                                           logits, temperature, top_p)
+            return tok, lp, common.action_mask(tok, env.n_actions)
+
+        # an env that is not pure jnp runs the same transition eagerly
+        self._env_compiled = bool(getattr(env, "jit_safe", False))
+        self._env_step = (jax.jit(rollout_env) if self._env_compiled
+                          else rollout_env)
+        self._sample = jax.jit(rollout_sample)
 
         # reference-model pass for signature parity with the compiled
         # engine's in-graph fold; the python path is the semantic
@@ -138,10 +165,9 @@ class RolloutEngine:
                     write = ~acted
                     if not write.any():
                         break
-                    with span("rollout.sample"):
-                        sampled, lp = common.sample_tokens(
-                            common.sample_rng(trng, t), logits_buf,
-                            self.temperature, self.top_p)
+                    with span("rollout.sample", compiled=1):
+                        sampled, lp, is_action = self._sample(
+                            logits_buf, trng, t)
                         sampled_np = to_host(sampled, "sampled", np.int32)
                         lp_np = to_host(lp, "logprob", np.float32)
 
@@ -153,30 +179,19 @@ class RolloutEngine:
                         turn_lengths[rows, turn] += 1
                         last_tok[rows] = sampled_np[rows]
 
-                        is_action = to_host(
-                            common.action_mask(sampled_np, env.n_actions),
-                            "action_mask")
-                        newly = write & is_action
+                        newly = write & to_host(is_action, "action_mask")
                         actions[newly] = sampled_np[newly] - ACTION_BASE
                         acted |= newly
 
                     advance_rows(sampled_np, write)
 
-            with span("rollout.env"):
-                # fallback action for rows that never emitted an action
-                actions = to_host(common.fallback_actions(
-                    actions, last_tok, active, acted, env.n_actions),
-                    "actions", np.int32)
+            with span("rollout.env", compiled=int(self._env_compiled)):
+                state, actions, res_obs, new_done = self._env_step(
+                    state, actions, last_tok, active, acted, trng)
+                actions = to_host(actions, "actions", np.int32)
                 n_turns[active] += 1
-
-                # env transition (inactive rows absorb inside env.step)
-                env_actions = np.where(active, actions, 0).astype(np.int32)
-                # freeze finished rows by making their action a no-op via
-                # done
-                state, res = env.step(state, jnp.asarray(env_actions),
-                                      common.env_rng(trng))
-                res_obs = to_host(res.obs_tokens, "obs")
-                new_done = to_host(res.done, "done")
+                res_obs = to_host(res_obs, "obs")
+                new_done = to_host(new_done, "done")
 
             # teacher-force the observation for still-running rows; rows
             # out of turn budget skip it (no generation can follow — the

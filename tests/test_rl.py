@@ -223,6 +223,59 @@ class TestRolloutEngine:
         np.testing.assert_array_equal(np.asarray(e1.tokens),
                                       np.asarray(e2.tokens))
 
+    @pytest.mark.parametrize("temperature", [0.0, 1.0])
+    @pytest.mark.parametrize("env_name", ["tictactoe", "connect_four"])
+    def test_env_program_matches_eager_env(self, setup, rng, env_name,
+                                           temperature):
+        """The env transition compiled (``jit_safe``) and run eagerly give
+        the same episodes."""
+        from repro.rl.rollout import RolloutEngine
+        model = setup[0].model
+        params = setup[1]
+        env = make_env(env_name)
+        eager_env = type("Eager", (type(env),), {"jit_safe": False})()
+        out = []
+        for e in (env, eager_env):
+            eng = RolloutEngine(model, e, max_turns=3, max_turn_tokens=3,
+                                max_context=160, temperature=temperature)
+            assert eng._env_compiled == e.jit_safe
+            out.append(eng.run(params, rng, 4)[0])
+        jitted, eager = out
+        for f in ("tokens", "gen_mask", "rewards", "context_len"):
+            np.testing.assert_array_equal(np.asarray(getattr(jitted, f)),
+                                          np.asarray(getattr(eager, f)))
+        np.testing.assert_allclose(np.asarray(jitted.logprobs),
+                                   np.asarray(eager.logprobs), atol=1e-5)
+
+    @pytest.mark.parametrize("temperature,top_p",
+                             [(0.0, 1.0), (1.0, 1.0), (0.7, 0.9)])
+    def test_sample_program_matches_eager_ops(self, setup, temperature,
+                                              top_p):
+        """One compiled sampler call gives the tokens, log-probs and action
+        mask of the eager key derivation, sampler and mask, at every token
+        position of a turn."""
+        from repro.rl.engine import common
+        from repro.rl.rollout import RolloutEngine
+        eng = RolloutEngine(setup[0].model, make_env("tictactoe"),
+                            temperature=temperature, top_p=top_p)
+        logits = jax.random.normal(jax.random.PRNGKey(5), (6, 512)) * 4
+        # favour action tokens so the mask reads both ways
+        logits = logits.at[:3, common.ACTION_BASE + 2].add(30.0)
+        trng = common.turn_rng(jax.random.PRNGKey(6), 1)
+        for t in range(3):
+            tok, lp, is_action = eng._sample(logits, trng, t)
+            want_tok, want_lp = common.sample_tokens(
+                common.sample_rng(trng, t), logits, temperature, top_p)
+            np.testing.assert_array_equal(np.asarray(tok),
+                                          np.asarray(want_tok))
+            np.testing.assert_allclose(np.asarray(lp), np.asarray(want_lp),
+                                       atol=1e-5)
+            np.testing.assert_array_equal(
+                np.asarray(is_action),
+                np.asarray(common.action_mask(want_tok, 9)))
+            assert np.asarray(is_action).tolist() == [True] * 3 + [False] * 3
+        assert eng._sample._cache_size() == 1     # one compile for every t
+
 
 class TestActionFallback:
     """Regression for the fallback mask: rows that never emit an action
@@ -273,6 +326,9 @@ class TestActionFallback:
         seen = []
 
         class RecordingTTT(TicTacToe):
+            # records on the host, so it cannot be traced
+            jit_safe = False
+
             def step(self, state, actions, rng_):
                 seen.append(np.asarray(actions))
                 return super().step(state, actions, rng_)

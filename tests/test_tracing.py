@@ -32,6 +32,7 @@ class Endless:
     """An environment whose episodes never end, so every row takes every
     turn and gets every observation."""
     n_actions = 4
+    jit_safe = True
 
     def reset(self, rng, batch):
         return EndlessState(done=jnp.zeros((batch,), bool),
@@ -138,6 +139,35 @@ def test_python_engine_names_its_programs(smoke_model):
         eng._prefill.lower(params, toks, cache).as_text()
     assert "@jit_rollout_decode" in eng._decode.lower(
         params, toks[:, 0], cache, jnp.ones((B,), bool)).as_text()
+    rng = jax.random.PRNGKey(0)
+    rows = jnp.ones((B,), bool)
+    assert "@jit_rollout_env" in eng._env_step.lower(
+        eng.env.reset(rng, B), toks[:, 0], toks[:, 0], rows, ~rows,
+        rng).as_text()
+    logits = jnp.zeros((B, model.cfg.vocab_size))
+    assert "@jit_rollout_sample" in \
+        eng._sample.lower(logits, rng, 0).as_text()
+
+
+@pytest.mark.parametrize("jit_safe", [True, False])
+def test_python_engine_env_program_follows_jit_safe(smoke_model, tmp_path,
+                                                    jit_safe):
+    from repro.rl.rollout import RolloutEngine
+    model, params = smoke_model
+    env = type("Declared", (Endless,), {"jit_safe": jit_safe})()
+    eng = RolloutEngine(model, env, max_turns=TURNS, max_turn_tokens=TOKENS,
+                        max_context=32)
+    rng = jax.random.PRNGKey(3)
+    eng.run(params, rng, B)                              # compile first
+    (_, stats), ev = profiled(tmp_path, lambda: eng.run(params, rng, B))
+    env_spans = [st for n, *_, st in ev if n == "rollout.env"]
+    sample_spans = [st for n, *_, st in ev if n == "rollout.sample"]
+    assert [st["compiled"] for st in env_spans] == [int(jit_safe)] * TURNS
+    assert sample_spans and all(st["compiled"] == 1 for st in sample_spans)
+    # either way the env transition reads the same three things a turn
+    sampled = int(stats.turn_lengths.max(axis=0).sum())
+    assert Counter(n for n, *_ in ev)["sync"] == 1 + 3 * sampled \
+        + 3 * TURNS + 1
 
 
 def test_compiled_engine_reads_returned_once_per_macro_step(smoke_model,
