@@ -27,7 +27,7 @@ sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(ROOT / "src"))
 
 
-def side(trainer, cfg, traffic, seed, stages, fault):
+def side(trainer, block, cfg, traffic, seed, stages, fault):
     """The program's three set-up steps for ``seed`` with ``fault``."""
     import jax
     from bench import harness, weights
@@ -35,11 +35,11 @@ def side(trainer, cfg, traffic, seed, stages, fault):
         s.before, s.after = None, []
     harness.plant(fault, trainer, stages, cfg["model"]["vocab_size"])
     trainer._rng = jax.random.PRNGKey(int(seed) % (2 ** 31 - 1))
-    params = weights.to_program(
-        weights.make_weights(cfg["model"], weights.seed_key(seed)))
+    params = weights.to_program(block, weights.make_weights(
+        block, cfg["model"], weights.seed_key(seed)))
     opt_state = trainer.optimizer.init(params)
     out = harness.program_side(trainer, params, opt_state, params, stages,
-                               cfg, traffic["algo"])
+                               block, cfg, traffic["algo"])
     return out[2:]
 
 
@@ -62,13 +62,14 @@ def main(argv=None):
     spec = common.load_benchmark(root)
     wl = common.workload(spec, args.workload)
     cfg = common.load_config(spec, wl["config"], root)
+    block = common.block(cfg, root)
     traffic = common.load_traffic(wl["traffic"], root)
     limits = common.load_limits(wl["name"], root)
     dev = harness.device_info(wl["chips"], True)
     print(f"device {dev}", flush=True)
 
     seeds = [int(s) for s in args.seeds.split(",")]
-    trainer = harness.build(cfg, traffic, seeds[0])[0]
+    trainer = harness.build(cfg, traffic, seeds[0], block)[0]
     stages = harness.wrap_stages(trainer)
 
     out = open(args.out, "w") if args.out else None
@@ -85,28 +86,30 @@ def main(argv=None):
 
     for i, seed in enumerate(seeds):
         t = time.perf_counter()
-        steps, g0, change = side(trainer, cfg, traffic, seed, stages, None)
+        steps, g0, change = side(trainer, block, cfg, traffic, seed, stages,
+                                 None)
         gc.collect()
-        ref = harness.reference_side(cfg, traffic, seed, steps, limits)
+        ref = harness.reference_side(block, cfg, traffic, seed, steps,
+                                     limits)
         gens = [s["gen_mask"] for s in steps]
         record("program", seed, check.readings(steps, g0, change, *ref,
                                                gens),
                time.perf_counter() - t)
         if i < args.control_seeds:
             t = time.perf_counter()
-            ctl = harness.reference_side(cfg, traffic, seed, steps, limits,
-                                         precision="fp8")
+            ctl = harness.reference_side(block, cfg, traffic, seed, steps,
+                                         limits, precision="fp8")
             record("control_fp8", seed,
                    check.readings(*ctl, *ref, gens), time.perf_counter() - t)
         if i < args.fault_seeds:
             for fault in ("half_batch", "token_altered",
                           "advantage_altered"):
                 t = time.perf_counter()
-                f_steps, f_g0, f_change = side(trainer, cfg, traffic, seed,
-                                               stages, fault)
+                f_steps, f_g0, f_change = side(trainer, block, cfg, traffic,
+                                               seed, stages, fault)
                 gc.collect()
-                f_ref = harness.reference_side(cfg, traffic, seed, f_steps,
-                                               limits)
+                f_ref = harness.reference_side(block, cfg, traffic, seed,
+                                               f_steps, limits)
                 record(fault, seed,
                        check.readings(f_steps, f_g0, f_change, *f_ref,
                                       [s["gen_mask"] for s in f_steps]),

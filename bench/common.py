@@ -5,6 +5,9 @@ metric, kernel or cell sits in a file of its own, found from the names in
 ``BENCHMARK.json``:
 
     bench/configs/<file named by the config entry>   sizes, as run
+    bench/blocks/<the config file's "block">.py       its model block:
+                                                      weights, reference,
+                                                      FLOPs
     bench/traffic/<traffic>.json                      the traffic mix
     bench/metrics/<metric>.py                         a per-layer reader
     bench/kernels/<kernel>.py                         a kernel's cost
@@ -43,6 +46,16 @@ def config_entry(spec: dict, name: str) -> dict:
 def load_config(spec: dict, name: str, root: Path = ROOT) -> dict:
     return json.loads((Path(root) / config_entry(spec, name)["file"])
                       .read_text())
+
+
+def block(cfg: dict, root: Path = ROOT):
+    """The model block that configuration ``cfg`` names
+    (``bench/blocks/__init__.py`` lists what one defines)."""
+    path = Path(root) / "bench" / "blocks" / f"{cfg['block']}.py"
+    if not path.exists():
+        raise SystemExit(f"configuration {cfg['name']!r} names block "
+                         f"{cfg['block']!r}: no file {path}")
+    return load_module(path, "block_" + cfg["block"])
 
 
 def load_traffic(name: str, root: Path = ROOT) -> dict:

@@ -3,7 +3,8 @@
 Set-up builds the program's ``EarlTrainer`` from the configuration and
 traffic files, with the traffic's algorithm settings and no path option,
 so the program's defaults are the path measured. Weights come from the
-seed (``bench/weights.py``). Three whole steps go through
+seed (``bench/weights.py``) by the configuration's block
+(``bench/blocks/<block>.py``). Three whole steps go through
 ``EarlTrainer.run_step``, the call the window drives; they compile every
 program the window uses, and what they produce is kept for the check.
 The window then runs whole steps until ``seconds`` have passed; each ends
@@ -102,7 +103,7 @@ def wrap_stages(trainer) -> dict:
     return stages
 
 
-def build(cfg: dict, traffic: dict, seed: int):
+def build(cfg: dict, traffic: dict, seed: int, block):
     """The program's trainer and its initial state."""
     from repro.configs import get_config
     from repro.core.stages import EarlTrainer
@@ -110,7 +111,8 @@ def build(cfg: dict, traffic: dict, seed: int):
     from repro.optim.adamw import adamw
     import importlib
 
-    mcfg = replace(get_config(cfg["arch"]), **cfg["model"])
+    mcfg = replace(get_config(cfg["arch"]),
+                   **block.program_config(cfg["model"]))
     model = build_model(mcfg)
     gen = importlib.import_module(f"bench.traffic.{traffic['generator']}")
     env = getattr(gen, traffic["class"])(**traffic.get("kwargs", {}))
@@ -125,8 +127,8 @@ def build(cfg: dict, traffic: dict, seed: int):
         max_context=traffic["max_context"], kl_coef=a["kl_coef"],
         clip_eps=a["clip_eps"], advantage=a["advantage"],
         temperature=a["temperature"], seed=int(seed) % (2 ** 31 - 1))
-    w = weights.make_weights(cfg["model"], weights.seed_key(seed))
-    params = weights.to_program(w)
+    w = weights.make_weights(block, cfg["model"], weights.seed_key(seed))
+    params = weights.to_program(block, w)
     opt_state = opt.init(params)
     return trainer, params, opt_state
 
@@ -171,15 +173,15 @@ def plant(fault, trainer, stages, vocab):
         raise ValueError(f"unknown fault {fault!r}")
 
 
-def program_side(trainer, params, opt_state, ref_params, stages, cfg,
-                 algo, n_steps=SETUP_STEPS):
+def program_side(trainer, params, opt_state, ref_params, stages, block,
+                 cfg, algo, n_steps=SETUP_STEPS):
     """Drive the first steps through ``run_step`` and keep, on the host,
     what each produced; the first gradient's norms from Adam's first
     moment, and the change of the weights."""
     import jax
     kept = []
     stages["expprep"].after.append(lambda exp: kept.append(exp) or exp)
-    names = sorted(weights.shapes(cfg["model"]))
+    names = sorted(block.shapes(cfg["model"]))
     params0 = params
     steps, g0 = [], None
     for k in range(n_steps):
@@ -194,20 +196,23 @@ def program_side(trainer, params, opt_state, ref_params, stages, cfg,
                       "adv": np.asarray(exp.advantages),
                       "loss": float(rec.loss)})
         if k == 0:
-            mu = weights.from_program(opt_state.mu, names)
+            mu = weights.from_program(block, opt_state.mu, names)
             g0 = {n: v / (1 - algo["b1"])
-                  for n, v in weights.leaf_norms(mu).items()}
+                  for n, v in weights.leaf_norms(block, mu).items()}
     stages["expprep"].after.pop()
-    change = weights.change_norms(weights.from_program(params, names),
-                                  weights.from_program(params0, names))
+    change = weights.change_norms(
+        block, weights.from_program(block, params, names),
+        weights.from_program(block, params0, names))
     return params, opt_state, steps, g0, change
 
 
-def reference_side(cfg, traffic, seed, steps, limits, precision="f32"):
+def reference_side(block, cfg, traffic, seed, steps, limits,
+                   precision="f32"):
     from bench.reference import Reference
-    ref = Reference(cfg["model"], traffic["algo"], precision=precision,
+    ref = Reference(block, cfg["model"], traffic["algo"],
+                    precision=precision,
                     rows_per_block=limits["rows_per_block"])
-    w0 = weights.make_weights(cfg["model"], weights.seed_key(seed))
+    w0 = weights.make_weights(block, cfg["model"], weights.seed_key(seed))
     return ref.follow(w0, steps)
 
 
@@ -230,6 +235,7 @@ def run_cell(spec, wl, seed, seconds, traced, *, t_start,
     the window."""
     import jax
     cfg = common.load_config(spec, wl["config"], root)
+    block = common.block(cfg, root)
     traffic = common.load_traffic(wl["traffic"], root)
     limits = common.load_limits(wl["name"], root)
     dev = device_info(wl["chips"], require_tpu)
@@ -237,13 +243,14 @@ def run_cell(spec, wl, seed, seconds, traced, *, t_start,
                                           "hbm_bytes_per_s": float("nan")})
     compiles()
 
-    trainer, params, opt_state = build(cfg, traffic, seed)
+    trainer, params, opt_state = build(cfg, traffic, seed, block)
     ref_params = params if traffic["algo"]["kl_coef"] > 0 else None
     stages = wrap_stages(trainer)
     plant(fault, trainer, stages, cfg["model"]["vocab_size"])
 
     params, opt_state, prog_steps, prog_g0, prog_change = program_side(
-        trainer, params, opt_state, ref_params, stages, cfg, traffic["algo"])
+        trainer, params, opt_state, ref_params, stages, block, cfg,
+        traffic["algo"])
 
     B = traffic["batch"]
     compiles0 = compiles()
@@ -298,7 +305,8 @@ def run_cell(spec, wl, seed, seconds, traced, *, t_start,
         shutil.rmtree(tmp, ignore_errors=True)
         ctx = trace.Context(
             reduction=red, step_s=step_s,
-            step_flops=[flops.step_flops(cfg["model"], x) for x in lens],
+            step_flops=[flops.step_flops(block, cfg["model"], x)
+                        for x in lens],
             peaks=peaks, chips=wl["chips"], config=cfg, traffic=traffic,
             context_lens=lens, root=root)
         metrics = per_layer(spec, wl, ctx, root)
@@ -317,7 +325,7 @@ def run_cell(spec, wl, seed, seconds, traced, *, t_start,
     del trainer, params, opt_state, ref_params, stages
     gc.collect()
     ref_steps, ref_g0, ref_change = reference_side(
-        cfg, traffic, seed, prog_steps, limits)
+        block, cfg, traffic, seed, prog_steps, limits)
     values = check.readings(prog_steps, prog_g0, prog_change, ref_steps,
                             ref_g0, ref_change,
                             [s["gen_mask"] for s in prog_steps])

@@ -1,13 +1,11 @@
-"""Plain reference of a dense Qwen2 decoder and of the RL step that the
-benchmark's traffic trains, in float32 at ``precision="highest"``.
+"""Plain reference of a decoder and of the RL step that the benchmark's
+traffic trains, in float32 at ``precision="highest"``.
 
-It follows the published Qwen2 block (arXiv:2407.10671, the
-``Qwen2ForCausalLM`` code of Hugging Face transformers): RMSNorm, grouped
-query attention with biased Q/K/V projections and rotary embeddings in
-the rotate-half form, a SwiGLU MLP, a final RMSNorm and a head that is the
-token table where the embeddings are tied. It imports nothing of the
-program. Weights are the benchmark's (``bench/weights.py``), stored in
-bfloat16 as the configuration states and widened to float32 on use.
+The decoder is the token table, the configuration's block stack
+(``bench/blocks/<block>.py``, its ``layers``), a final RMSNorm and a head
+that is the token table where the embeddings are tied. It imports nothing
+of the program. Weights are the benchmark's (``bench/weights.py``), stored
+in bfloat16 as the configuration states and widened to float32 on use.
 
 The RL step is the traffic's ``algo`` settings written out: leave-one-out
 REINFORCE advantages, a PPO-clip surrogate whose old log-probs are the
@@ -15,10 +13,10 @@ reference's own (ratio 1), the k3 KL to the reference weights, a mean
 over generated tokens, global-norm clipping and Adam. After each update
 the weights are stored back in bfloat16, the configuration's dtype.
 
-``precision="fp8"`` is the control: every weight matmul takes operands
-rounded to float8_e4m3 with a per-tensor scale, the precision below
-bfloat16. Batches are computed in blocks of rows so that logits of a full
-vocabulary fit the device.
+``precision="fp8"`` is the control: every weight matmul (``mm``) takes
+operands rounded to float8_e4m3 with a per-tensor scale, the precision
+below bfloat16. Batches are computed in blocks of rows so that logits of
+a full vocabulary fit the device.
 """
 from __future__ import annotations
 
@@ -28,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import LAYER_KEYS, change_norms, leaf_norms
+from bench.weights import change_norms, leaf_norms
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -42,7 +40,8 @@ def _q8(x):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _mm(spec, x, w, precision):
+def mm(spec, x, w, precision):
+    """A weight matmul in float32, or with float8 operands for "fp8"."""
     x = x.astype(jnp.float32)
     w = w.astype(jnp.float32)
     if precision == "fp8":
@@ -50,69 +49,33 @@ def _mm(spec, x, w, precision):
     return jnp.einsum(spec, x, w, precision=HIGHEST)
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
 
 
-def _rope(x, theta):
-    """x: (B, T, heads, hd), rotate-half form."""
-    T, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd)
-    ang = np.arange(T, dtype=np.float32)[:, None] * inv[None, :]
-    ang = np.concatenate([ang, ang], axis=-1)            # (T, hd)
-    cos = jnp.asarray(np.cos(ang))[None, :, None, :]
-    sin = jnp.asarray(np.sin(ang))[None, :, None, :]
-    half = hd // 2
-    rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * cos + rot * sin
-
-
-def _layer(m, precision, x, p):
-    B, T, _ = x.shape
-    H, KV, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    h = _rms(x, p["attn_norm"], m["rms_eps"])
-    q = _mm("btd,dh->bth", h, p["wq"], precision)
-    k = _mm("btd,dh->bth", h, p["wk"], precision)
-    v = _mm("btd,dh->bth", h, p["wv"], precision)
-    if "bq" in p:
-        q = q + p["bq"].astype(jnp.float32)
-        k = k + p["bk"].astype(jnp.float32)
-        v = v + p["bv"].astype(jnp.float32)
-    q = _rope(q.reshape(B, T, H, hd), m["rope_theta"])
-    k = _rope(k.reshape(B, T, KV, hd), m["rope_theta"])
-    v = v.reshape(B, T, KV, hd)
-    rep = H // KV                                        # q head i -> kv i//rep
-    k = jnp.repeat(k, rep, axis=2)
-    v = jnp.repeat(v, rep, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HIGHEST) / np.sqrt(hd)
-    causal = np.tril(np.ones((T, T), bool))
-    s = jnp.where(causal[None, None], s, -jnp.inf)
-    a = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", a, v, precision=HIGHEST)
-    x = x + _mm("bth,hd->btd", o.reshape(B, T, H * hd), p["wo"], precision)
-    h = _rms(x, p["mlp_norm"], m["rms_eps"])
-    g = _mm("btd,df->btf", h, p["w_gate"], precision)
-    u = _mm("btd,df->btf", h, p["w_up"], precision)
-    x = x + _mm("btf,fd->btd", jax.nn.silu(g) * u, p["w_down"], precision)
+def scan_layers(layer, x, w, keys):
+    """``layer(x, p)`` over the leaves ``keys`` of ``w`` stacked on their
+    leading axis, each layer recomputed in the backward pass."""
+    stack = {k: w[k] for k in keys if k in w}
+    step = jax.checkpoint(layer)
+    x, _ = jax.lax.scan(lambda x, p: (step(x, p), None), x, stack)
     return x
 
 
-def logits(w, tokens, m, precision="f32"):
+def logits(block, w, tokens, m, precision="f32"):
     """(B, T) int tokens -> (B, T, V) float32 logits."""
     x = w["embed"].astype(jnp.float32)[tokens]
-    layers = {k: w[k] for k in LAYER_KEYS if k in w}
-    step = jax.checkpoint(partial(_layer, m, precision))
-    x, _ = jax.lax.scan(lambda x, p: (step(x, p), None), x, layers)
-    x = _rms(x, w["final_norm"], m["rms_eps"])
+    x = block.layers(m, precision, x, w)
+    x = rms(x, w["final_norm"], m["rms_eps"])
     if m["tie_embeddings"]:
-        return _mm("btd,vd->btv", x, w["embed"], precision)
-    return _mm("btd,dv->btv", x, w["head"], precision)
+        return mm("btd,vd->btv", x, w["embed"], precision)
+    return mm("btd,dv->btv", x, w["head"], precision)
 
 
-def token_logprobs(w, tokens, m, precision="f32"):
+def token_logprobs(block, w, tokens, m, precision="f32"):
     """(B, T) -> (B, T-1): log p(token[t+1] | tokens[:t+1])."""
-    lg = logits(w, tokens, m, precision)[:, :-1]
+    lg = logits(block, w, tokens, m, precision)[:, :-1]
     lp = jax.nn.log_softmax(lg, axis=-1)
     return jnp.take_along_axis(lp, tokens[:, 1:, None], axis=-1)[..., 0]
 
@@ -123,8 +86,9 @@ def advantages(rewards):
     return r - (jnp.sum(r) - r) / (B - 1)
 
 
-def _block_loss(w, tokens, gen, adv, ref_lp, denom, m, algo, precision):
-    lp = token_logprobs(w, tokens, m, precision)
+def _rows_loss(w, tokens, gen, adv, ref_lp, denom, block, m, algo,
+                precision):
+    lp = token_logprobs(block, w, tokens, m, precision)
     mask = gen[:, 1:].astype(jnp.float32)
     ratio = jnp.exp(lp - jax.lax.stop_gradient(lp))
     a = adv[:, None]
@@ -137,15 +101,19 @@ def _block_loss(w, tokens, gen, adv, ref_lp, denom, m, algo, precision):
 
 
 class Reference:
-    """The reference trainer for one configuration and traffic mix."""
+    """The reference trainer for one configuration (its block and sizes
+    ``m``) and traffic mix."""
 
-    def __init__(self, m: dict, algo: dict, *, precision: str = "f32",
+    def __init__(self, block, m: dict, algo: dict, *, precision: str = "f32",
                  rows_per_block: int = 4):
-        self.m, self.algo, self.precision = m, algo, precision
+        self.block, self.m, self.algo = block, m, algo
+        self.precision = precision
         self.rb = rows_per_block
-        self._lp = jax.jit(partial(token_logprobs, m=m, precision=precision))
+        self._lp = jax.jit(partial(token_logprobs, block, m=m,
+                                   precision=precision))
         self._grad = jax.jit(jax.value_and_grad(
-            partial(_block_loss, m=m, algo=algo, precision=precision),
+            partial(_rows_loss, block=block, m=m, algo=algo,
+                    precision=precision),
             has_aux=True))
         self._add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),
                             donate_argnums=(0,))
@@ -153,13 +121,13 @@ class Reference:
             lambda x: x.astype(jnp.float32), w))
         self._adam = jax.jit(self._adam_step, donate_argnums=(2, 3))
 
-    def _blocks(self, n):
+    def _row_slices(self, n):
         return [slice(i, min(i + self.rb, n)) for i in range(0, n, self.rb)]
 
     def logprobs(self, w, tokens):
         tokens = np.asarray(tokens)
         return np.concatenate([np.asarray(self._lp(w, tokens[s]))
-                               for s in self._blocks(len(tokens))])
+                               for s in self._row_slices(len(tokens))])
 
     def grad(self, w, tokens, gen, adv, ref_lp):
         """Mean loss over the batch's generated tokens and its float32
@@ -170,7 +138,7 @@ class Reference:
         g = None
         loss = 0.0
         lps = []
-        for s in self._blocks(len(gen)):
+        for s in self._row_slices(len(gen)):
             (l, lp), gb = self._grad(w, tokens[s], gen[s], adv[s],
                                      ref_lp[s], denom)
             g = gb if g is None else self._add(g, gb)
@@ -215,10 +183,10 @@ class Reference:
             loss, g, lp = self.grad(w, tokens, s["gen_mask"], adv, ref_lp)
             new_w, mu, nu, gc = self._adam(np.float32(t), w, mu, nu, g)
             if t == 1:
-                g0_norms = leaf_norms(gc)
+                g0_norms = leaf_norms(self.block, gc)
             del g, gc
             w = new_w
             out.append({"lp": lp, "ref_lp": ref_lp, "adv": adv,
                         "loss": loss})
         del mu, nu
-        return out, g0_norms, change_norms(w, w0)
+        return out, g0_norms, change_norms(self.block, w, w0)

@@ -1,11 +1,13 @@
-"""Every cell of BENCHMARK.json resolves to its files by name; a new cell
-needs only new files; without a TPU the harness refuses to measure."""
+"""Every cell of BENCHMARK.json resolves to its files by name; a new cell,
+of a new block too, needs only new files; without a TPU the harness
+refuses to measure."""
 import importlib
 import json
 import os
 import shutil
 import subprocess
 import sys
+from dataclasses import replace
 from pathlib import Path
 
 import numpy as np
@@ -15,21 +17,40 @@ REPO = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from bench import check, common, flops, harness, trace  # noqa: E402
+from bench import check, common, flops, harness, trace, weights  # noqa: E402
 import tinycell  # noqa: E402
+from repro.configs import get_config  # noqa: E402
+from repro.models.registry import build_model  # noqa: E402
 
 SPEC = common.load_benchmark(REPO)
 E2E = {"tokens_per_s", "step_s_p90", "peak_hbm_gib", "setup_s"}
+
+
+def _block_fits_the_program(cfg, root):
+    """The configuration's block resolves, and the benchmark's weights
+    have the program's parameter tree, leaf for leaf."""
+    import jax
+    block = common.block(cfg, root)
+    m = cfg["model"]
+    model = build_model(replace(get_config(cfg["arch"]),
+                                **block.program_config(m)))
+    want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    got = weights.to_program(
+        block, {n: jax.ShapeDtypeStruct(s, jax.numpy.bfloat16)
+                for n, s in block.shapes(m).items()})
+    assert (jax.tree.map(lambda x: x.shape, got)
+            == jax.tree.map(lambda x: x.shape, want))
+    assert set(block.LAYER_KEYS) >= {n for n in block.shapes(m)
+                                     if block.PROGRAM_PATH[n][0] == "layers"}
+    assert block.matmul_weights(m) > 0
+    return block
 
 
 @pytest.mark.parametrize("wl", SPEC["workloads"], ids=lambda w: w["name"])
 def test_workload_resolves(wl):
     cfg = common.load_config(SPEC, wl["config"], REPO)
     assert cfg["name"] == wl["config"]
-    for k in ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-              "d_ff", "vocab_size", "qkv_bias", "rope_theta", "rms_eps",
-              "tie_embeddings"):
-        assert k in cfg["model"], k
+    _block_fits_the_program(cfg, REPO)
     traffic = common.load_traffic(wl["traffic"], REPO)
     gen = importlib.import_module(f"bench.traffic.{traffic['generator']}")
     assert hasattr(gen, traffic["class"])
@@ -61,14 +82,22 @@ def test_metrics_are_known_and_move_end_to_end_metrics():
 
 
 def test_new_cell_needs_only_new_files(tmp_path):
-    root = tinycell.make_root(tmp_path)
+    root = tinycell.make_moe_root(tmp_path)
     spec = common.load_benchmark(root)
-    wl = common.workload(spec, tinycell.CELL)
-    assert common.load_config(spec, wl["config"], root)["model"][
-        "vocab_size"] == 512
-    assert common.load_traffic(wl["traffic"], root)["batch"] == 8
-    assert set(common.load_limits(wl["name"], root)["limits"]) == set(
-        check.NUMBERS)
+    for cell, block in ((tinycell.CELL, "qwen2"),
+                        (tinycell.MOE_CELL, "toymoe")):
+        wl = common.workload(spec, cell)
+        cfg = common.load_config(spec, wl["config"], root)
+        assert cfg["model"]["vocab_size"] == 512 and cfg["block"] == block
+        _block_fits_the_program(cfg, root)
+        assert common.load_traffic(wl["traffic"], root)["batch"] == 8
+        assert set(common.load_limits(wl["name"], root)["limits"]) == set(
+            check.NUMBERS)
+    # the second block is a new file, found under the root by its name
+    assert (root / "bench/blocks/toymoe.py").exists()
+    assert not (REPO / "bench/blocks/toymoe.py").exists()
+    with pytest.raises(SystemExit, match="no file"):
+        common.block({"name": "x", "block": "absent"}, root)
     # a metric added as a file is found by its name
     (root / "bench" / "metrics" / "new_metric.x.py").write_text(
         "def read(ctx):\n    return None\n")
@@ -118,6 +147,7 @@ def test_new_kernel_needs_only_new_files(tmp_path):
     spec = common.load_benchmark(root)
     wl = common.workload(spec, tinycell.CELL)
     cfg = common.load_config(spec, wl["config"], root)
+    block = common.block(cfg, root)
     traffic = common.load_traffic(wl["traffic"], root)
     red = trace.reduce(trace.read(
         str(REPO / "bench" / "testdata" / "tiny.xplane.pb")))
@@ -125,7 +155,8 @@ def test_new_kernel_needs_only_new_files(tmp_path):
     peaks = trace.peaks("TPU v5 lite")
     ctx = trace.Context(
         reduction=red, step_s=[red.window_s],
-        step_flops=[flops.step_flops(cfg["model"], x) for x in lens],
+        step_flops=[flops.step_flops(block, cfg["model"], x)
+                    for x in lens],
         peaks=peaks, chips=1, config=cfg, traffic=traffic,
         context_lens=lens, root=root)
     out = harness.per_layer(spec, wl, ctx, root)
